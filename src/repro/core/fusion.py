@@ -40,12 +40,12 @@ PyTree = Any
 
 
 def default_use_kernel() -> bool:
-    """Kernel fusion default: on when Pallas compiles for real (TPU), or when
-    explicitly requested; off for the CPU interpret path (where the
-    tree_map reference is faster than an interpreted kernel). Shares
-    ``kernels.ops.pallas_interpret()`` — the per-call env resolution —
-    so the fuse-path default and the kernels' interpret/compile switch
-    can never disagree (both re-read the env on every call)."""
+    """Kernel fusion default: on when Pallas compiles for real (a TPU
+    backend), off on any other backend, where the tree_map reference is
+    faster than an interpreted kernel. Shares
+    ``kernels.ops.pallas_interpret()``'s platform rule, so the fuse-path
+    default and the kernels' interpret/compile switch can never disagree.
+    ``REPRO_FUSION_KERNEL=1``/``0`` overrides the default."""
     if os.environ.get("REPRO_FUSION_KERNEL"):
         return os.environ["REPRO_FUSION_KERNEL"] == "1"
     from repro.kernels.ops import pallas_interpret

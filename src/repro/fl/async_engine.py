@@ -343,7 +343,7 @@ def lower_async_event(task, cfg, mesh, *, use_kernel: bool | None = None):
                       else NamedSharding(mesh, P()))), param_shapes)
     wspec = jax.ShapeDtypeStruct((K,), jnp.float32,
                                  sharding=NamedSharding(mesh, P()))
-    with mesh:      # jax 0.4.x: Mesh is the context manager
+    with mesh:
         return engine.event_fn.lower(sspecs, gspecs, stacked_specs, wspec)
 
 

@@ -51,6 +51,7 @@ paper's structural alignment removes — see launch/fl_dryrun.py records).
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Callable
 
 import jax
@@ -69,6 +70,15 @@ from repro.optim.optimizers import Optimizer
 
 PyTree = Any
 
+_log = logging.getLogger(__name__)
+
+
+def _say_reference_path(kernel: str, why: str) -> None:
+    """A TPU backend runs the compiled Pallas kernels by default; every
+    place the engine takes the reference path there instead says so."""
+    if jax.default_backend() == "tpu":
+        _log.warning("%s kernel off on TPU: %s", kernel, why)
+
 
 def _client_sharding(mesh, ndim: int) -> NamedSharding:
     """Leading cohort axis on "data", everything else replicated."""
@@ -79,12 +89,16 @@ def resolve_use_kernel(use_kernel: bool | None, mesh) -> bool:
     """The engine's effective fusion fast-path decision — THE single copy
     of the rule (consumers recording it, e.g. launch/fl_dryrun.py, call
     this instead of re-deriving it): caller's choice (None = the
-    env-driven ``fusion.default_use_kernel()``), forced off on
+    platform-driven ``fusion.default_use_kernel()``), forced off on
     multi-device meshes where the tree reduction is the path that lowers
-    to one all-reduce."""
+    to one all-reduce (a warning on a TPU backend says so)."""
     if use_kernel is None:
         use_kernel = fusion_lib.default_use_kernel()
-    return bool(use_kernel) and (mesh is None or mesh.size == 1)
+    if use_kernel and mesh is not None and mesh.size > 1:
+        _say_reference_path("fusion", f"{mesh.size}-device mesh, where the "
+                            "tree reduction lowers to one all-reduce")
+        return False
+    return bool(use_kernel)
 
 
 def resolve_compute_dtype(compute_dtype, method: FedMethod):
@@ -235,10 +249,24 @@ class RoundEngine:
             lambda l: np.array(
                 np.broadcast_to(l[None], (population,) + l.shape)), one)
 
+    def place_cohort(self, tree: PyTree) -> PyTree:
+        """Put a cohort-axis tree (batches, client states) on the mesh with
+        the leading axis split over "data", so each device receives only
+        its own clients. Identity without a mesh; a cohort that does not
+        divide evenly is left to the round's in-graph constraint."""
+        if self.mesh is None or self.cohort_size % self.mesh.shape["data"]:
+            return tree
+        return jax.tree_util.tree_map(
+            lambda l: jax.device_put(l, _client_sharding(self.mesh,
+                                                         np.ndim(l))), tree)
+
     def run_round(self, state: PyTree, global_params: PyTree,
                   batches: PyTree, weights=None, group_weights=None,
                   malicious=None) -> tuple:
-        state, out = self.round_fn(state, global_params, batches,
+        state = {"server": state["server"],
+                 "clients": self.place_cohort(state["clients"])}
+        state, out = self.round_fn(state, global_params,
+                                   self.place_cohort(batches),
                                    self._w32(weights),
                                    self._w32(group_weights),
                                    self._mal(malicious))
@@ -251,8 +279,9 @@ class RoundEngine:
                  group_weights=None, malicious=None) -> tuple:
         """One cohort tile of a tiled round: local phase + fuse only.
         Returns (new_client_states, fuse_out)."""
-        return self.tile_fn(client_states, server_state, global_params,
-                            batches, self._w32(weights),
+        return self.tile_fn(self.place_cohort(client_states), server_state,
+                            global_params, self.place_cohort(batches),
+                            self._w32(weights),
                             self._w32(group_weights),
                             self._mal(malicious))
 
@@ -284,8 +313,9 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
     records reflect the kernel path).
     use_local_kernel: route the default client_update's optimizer tail
     through the fused Pallas ``local_step`` kernel (DESIGN.md §15);
-    silently a no-op for methods without ``fused_local_step`` (their
-    client_update/local_opt overrides never reach the shared tail).
+    a no-op for methods without ``fused_local_step`` (their
+    client_update/local_opt overrides never reach the shared tail),
+    logged as a warning on a TPU backend like every forced-off kernel.
     method: an explicit FedMethod instance; default resolves
     ``methods.get(cfg.method)`` from the registry.
 
@@ -331,7 +361,10 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
         if not rule.active:
             rule = None
         elif use_kernel and rule.reduces:
-            use_kernel = False   # sort-based reductions have no kernel path
+            # sort-based reductions have no kernel path
+            _say_reference_path("fusion", f"robust rule {rule.describe()} "
+                                "reduces without it")
+            use_kernel = False
     # §15 performance knobs, resolved through THE single-copy rules so
     # direct engine drives hit the same refusals as FLConfig validation
     cdtype = resolve_compute_dtype(getattr(cfg, "compute_dtype", None),
@@ -341,8 +374,10 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
         codec = codec_lib.parse_codec(cfg.codec)
         codec_lib.check_codec_support(meth, codec, rule)
     steps = cfg.local_epochs * cfg.steps_per_epoch
-    use_local_kernel = (bool(use_local_kernel)
-                        and compat_lib.supports(meth, "kernel"))
+    if use_local_kernel and not compat_lib.supports(meth, "kernel"):
+        _say_reference_path("local_step", f"{meth.name} runs its own "
+                            "local update")
+        use_local_kernel = False
     ctx = MethodContext(task=task, cfg=cfg, population=cfg.population,
                         cohort_size=n,
                         local_steps=steps,
@@ -352,7 +387,7 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
                         robust=rule if (rule is not None and rule.reduces)
                         else None,
                         local_unroll=resolve_local_unroll(cfg, steps),
-                        use_local_kernel=use_local_kernel)
+                        use_local_kernel=bool(use_local_kernel))
     meth.check(ctx)
 
     def init_server_state(global_params):
@@ -549,7 +584,7 @@ def lower_round(task, cfg, mesh, batch_elems: dict, *, local_steps: int,
                                       sharding=NamedSharding(mesh, P())),
                  jax.ShapeDtypeStruct(kshape.shape, kshape.dtype,
                                       sharding=NamedSharding(mesh, P())))
-    with mesh:      # jax 0.4.x: Mesh is the context manager
+    with mesh:
         return engine.round_fn.lower(sspecs, gspecs, bspecs, wspec, gwspec,
                                      mspec)
 

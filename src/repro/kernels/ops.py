@@ -1,10 +1,8 @@
 """jit'd public wrappers around the Pallas kernels: padding, layout, bias,
-and group-pairing gathers. ``interpret`` defaults to True (CPU validation);
-on real TPU set REPRO_PALLAS_COMPILE=1.
+and group-pairing gathers. The platform picks the kernel mode: compiled
+on a TPU backend, interpreted everywhere else (``pallas_interpret``).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,14 +14,29 @@ from repro.kernels.paired_fusion import paired_fusion_kernel
 from repro.kernels.ssd_update import ssd_update_kernel
 
 
+# VMEM bytes one (N, bm) fp32 fusion block may take; Pallas double-buffers
+# it, and the kernel body holds one fp32 product of the same size, so a
+# step stays well inside the 16 MiB of scoped VMEM on a v5e core.
+FUSION_BLOCK_BYTES = 2 << 20
+
+
 def pallas_interpret() -> bool:
     """Whether Pallas kernels run in interpret mode — THE single copy of
-    the rule, resolved PER CALL (never frozen at import: monkeypatched
-    tests and programmatic launchers set REPRO_PALLAS_COMPILE after this
-    module loads). ``fusion.default_use_kernel()`` reads the same env the
-    same way, so "compile for real" and "kernels on by default" flip
-    together."""
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+    the rule: compiled on a TPU backend, interpreted on any other. Read
+    per call, never at import (importing must not touch device state).
+    ``fusion.default_use_kernel()`` follows the same rule, so "compile for
+    real" and "kernels on by default" flip together."""
+    return jax.default_backend() != "tpu"
+
+
+def fusion_block_cols(n: int, m: int, bm: int = 1024) -> int:
+    """Column tile of the (N, M) fusion kernel: at most ``bm``, a lane
+    multiple (128) no wider than M needs, and narrow enough that the
+    (N, bm) fp32 block fits FUSION_BLOCK_BYTES (N rounded up to the
+    8-row sublane tile). Never below one lane tile."""
+    rows = -(-n // 8) * 8
+    cap = max(128, FUSION_BLOCK_BYTES // (rows * 4) // 128 * 128)
+    return min(bm, cap, -(-m // 128) * 128)
 
 
 def _pad_to(x, mult, axis):
@@ -113,9 +126,9 @@ def paired_fusion(stacked, weights, *, group_axis=None, perms=None,
     unit the engine's flatten-to-(N, M) fast path (core/fusion.py) calls
     per bucket. Optional Fed2 pairing: reorder each client's group blocks
     (group_axis = (axis, n_groups) in the per-client view) by ``perms``
-    (N, G) before the reduction. The tile is shrunk to the smallest lane
-    multiple covering small inputs so tiny buckets don't pad to a full
-    ``bm`` block."""
+    (N, G) before the reduction. The column tile comes from
+    ``fusion_block_cols``: small buckets don't pad to a full ``bm`` block,
+    and large cohorts narrow it to keep the block's VMEM bounded."""
     n = stacked.shape[0]
     x = stacked
     if perms is not None and group_axis is not None:
@@ -130,7 +143,7 @@ def paired_fusion(stacked, weights, *, group_axis=None, perms=None,
         x = xr.reshape(x.shape)
     flat = x.reshape(n, -1)
     m0 = flat.shape[1]
-    bm = min(bm, -(-m0 // 128) * 128)       # lane-aligned, no 1024-padding
+    bm = fusion_block_cols(n, m0, bm)
     flat, _ = _pad_to(flat, bm, 1)
     w = jnp.asarray(weights, jnp.float32)
     w = w / jnp.sum(w)

@@ -106,7 +106,7 @@ def build_lowered(arch: str, shape_name: str, *, multi_pod: bool,
     pshard = shd.param_shardings(param_shapes, cfg, mesh)
     pspecs = _spec_tree(param_shapes, pshard)
 
-    with mesh:      # jax 0.4.x: Mesh is the context manager
+    with mesh:
         if shape.mode == "train":
             from repro.launch.analytic import param_counts
             n_par = param_counts(cfg)["total"]

@@ -81,6 +81,8 @@ def main():
     if bad:
         raise SystemExit(f"unknown scenarios {bad}; available: "
                          f"{', '.join(scenarios_lib.available())}")
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     run_many(names, mesh_kind=args.mesh, outdir=args.out,
              rounds=args.rounds, train_size=args.train_size)
 

@@ -69,38 +69,16 @@ def run_lm(args):
     return float(loss)
 
 
-def run_fl(args):
+def build_fl_run(args):
+    """The fl-mode run that the parsed flags describe, ready for
+    ``run_federated``: (task, FLConfig, parts, get_batch, test_batches)."""
     import importlib
 
     from repro.data.synthetic import (dirichlet_partition,
                                       make_image_dataset, nxc_partition)
     from repro.fl import alignment as alignment_lib
     from repro.fl import methods as methods_lib
-    from repro.fl.runtime import FLConfig, cnn_task, run_federated
-
-    if args.scenario:
-        # a registered scenario IS the full run config — everything else
-        # on the command line is pinned by the spec (fl/scenarios.py)
-        from repro.fl import scenarios as scenarios_lib
-        spec = scenarios_lib.get(args.scenario)
-        rec = scenarios_lib.run_scenario(spec, log=print)
-        print(f"scenario {spec.name} ({spec.protocol_label()}, "
-              f"{spec.method}): final acc {rec.final_acc:.4f}, "
-              f"best {rec.best_acc:.4f}")
-        return rec
-
-    if args.dry_run:
-        # lower (don't run) one engine round on the 1-device host mesh —
-        # the sharded code path without TPUs. Uses fl_dryrun's reduced
-        # vgg9 case regardless of --arch; see repro.launch.fl_dryrun for
-        # the production-mesh matrix.
-        from repro.launch.fl_dryrun import run_matrix
-        recs = run_matrix(mesh_kind="host", methods=(args.method,),
-                          families=("cnn",), clients=args.nodes,
-                          local_steps=args.local_epochs *
-                          args.steps_per_epoch,
-                          batch=args.batch)
-        return recs
+    from repro.fl.runtime import FLConfig, cnn_task
 
     mod = importlib.import_module(
         f"repro.configs.{args.arch.replace('-', '_').replace('.', '_')}")
@@ -147,14 +125,47 @@ def run_fl(args):
                   codec=args.codec or None,
                   local_unroll=args.local_unroll,
                   alignment=args.alignment)
-    h = run_federated(cnn_task(cfg), fl, parts, get_batch, test_batches,
+    return cnn_task(cfg), fl, parts, get_batch, test_batches
+
+
+def run_fl(args):
+    from repro.fl.runtime import run_federated
+
+    if args.scenario:
+        # a registered scenario IS the full run config — everything else
+        # on the command line is pinned by the spec (fl/scenarios.py)
+        from repro.fl import scenarios as scenarios_lib
+        spec = scenarios_lib.get(args.scenario)
+        rec = scenarios_lib.run_scenario(spec, log=print)
+        print(f"scenario {spec.name} ({spec.protocol_label()}, "
+              f"{spec.method}): final acc {rec.final_acc:.4f}, "
+              f"best {rec.best_acc:.4f}")
+        return rec
+
+    if args.dry_run:
+        # lower (don't run) one engine round on the 1-device host mesh —
+        # the sharded code path without TPUs. Uses fl_dryrun's reduced
+        # vgg9 case regardless of --arch; see repro.launch.fl_dryrun for
+        # the production-mesh matrix.
+        from repro.launch.fl_dryrun import run_matrix
+        recs = run_matrix(mesh_kind="host", methods=(args.method,),
+                          families=("cnn",), clients=args.nodes,
+                          local_steps=args.local_epochs *
+                          args.steps_per_epoch,
+                          batch=args.batch)
+        return recs
+
+    task, fl, parts, get_batch, test_batches = build_fl_run(args)
+    h = run_federated(task, fl, parts, get_batch, test_batches,
                       latency=args.latency, log=print,
                       use_local_kernel=args.use_local_kernel)
     print("final acc:", h["acc"][-1])
     return h
 
 
-def main():
+def parse_args(argv=None):
+    """Parse and cross-check the launcher's flags (``argv=None``: the
+    process's own command line)."""
     from repro.fl import alignment as alignment_lib
     from repro.fl import attacks as attacks_lib
     from repro.fl import codec as codec_lib
@@ -274,11 +285,7 @@ def main():
                     help="fl mode: lower+compile one engine round (reduced "
                          "vgg9, chosen --method) on the host mesh instead "
                          "of training")
-    args = ap.parse_args()
-    if args.list_capabilities:
-        from repro.fl import compat as compat_lib
-        print(compat_lib.capability_table())
-        return
+    args = ap.parse_args(argv)
     if args.dry_run and args.mode != "fl":
         ap.error("--dry-run is only supported with --mode fl")
     if args.scenario and args.mode != "fl":
@@ -302,7 +309,20 @@ def main():
                  "--use-local-kernel are only supported with --mode fl")
     if args.mode != "fl" and args.alignment != "grouped":
         ap.error("--alignment is only supported with --mode fl")
-    (run_lm if args.mode == "lm" else run_fl)(args)
+    return args
+
+
+def main(argv=None):
+    """Run the launcher on ``argv`` (None: the process's command line) and
+    return the run's result — fl mode: ``run_federated``'s history."""
+    args = parse_args(argv)
+    if args.list_capabilities:
+        from repro.fl import compat as compat_lib
+        print(compat_lib.capability_table())
+        return None
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
+    return (run_lm if args.mode == "lm" else run_fl)(args)
 
 
 if __name__ == "__main__":

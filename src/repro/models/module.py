@@ -90,11 +90,8 @@ def maybe_shard(x, *spec):
 
     Use module.BATCH for the ("pod","data") batch axes."""
     import jax.sharding as shx
-    try:
-        mesh = shx.get_abstract_mesh()
-        names = set(mesh.axis_names or ())
-    except Exception:  # pragma: no cover - very old jax
-        return x
+    mesh = shx.get_abstract_mesh()
+    names = set(mesh.axis_names or ())
     if not names:
         return x
     sizes = dict(zip(mesh.axis_names, mesh.shape.values())) \

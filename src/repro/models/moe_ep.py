@@ -126,13 +126,6 @@ def moe_apply_ep(p, x, cfg, mesh, *, axis: str = "model",
     cf = capacity_factor or cfg.capacity_factor
     capacity = max(1, int(cf * cfg.top_k * n_loc / nsh))
 
-    try:                                    # jax >= 0.6
-        from jax import shard_map
-        check_kw = {"check_vma": False}
-    except ImportError:                     # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
-        check_kw = {"check_rep": False}
-
     def body(p_loc, x_loc):
         bl, sl, _ = x_loc.shape
         y, aux = _local_moe(p_loc, x_loc.reshape(bl * sl, d), cfg,
@@ -140,8 +133,8 @@ def moe_apply_ep(p, x, cfg, mesh, *, axis: str = "model",
         return y.reshape(bl, sl, d), aux
 
     pspecs = jax.tree_util.tree_map(lambda _: P(), p)  # replicated weights
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(pspecs, P("data", None, None)),
-                   out_specs=(P("data", None, None), P()),
-                   **check_kw)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(pspecs, P("data", None, None)),
+                       out_specs=(P("data", None, None), P()),
+                       check_vma=False)
     return fn(p, x)

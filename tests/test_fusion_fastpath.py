@@ -104,5 +104,11 @@ def test_default_use_kernel_env(monkeypatch):
     monkeypatch.setenv("REPRO_FUSION_KERNEL", "0")
     assert not fusion.default_use_kernel()
     monkeypatch.delenv("REPRO_FUSION_KERNEL")
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
+    # unset: the platform decides — kernel fusion on a TPU backend only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fusion.default_use_kernel()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert not fusion.default_use_kernel()
+    # the env overrides the platform either way
+    monkeypatch.setenv("REPRO_FUSION_KERNEL", "1")
     assert fusion.default_use_kernel()

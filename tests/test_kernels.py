@@ -61,7 +61,8 @@ def test_feature_stats(b, i, dtype):
 
 
 @pytest.mark.parametrize("n,shape", [(4, (33, 7)), (10, (128,)),
-                                     (3, (5, 6, 7)), (2, (1,))])
+                                     (3, (5, 6, 7)), (2, (1,)),
+                                     (600, (3000,))])
 def test_paired_fusion(n, shape):
     s = jax.random.normal(KEY, (n,) + shape)
     w = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (n,))) + 0.1
@@ -69,6 +70,17 @@ def test_paired_fusion(n, shape):
     wn = w / jnp.sum(w)
     want = ref.paired_fusion_ref(s.reshape(n, -1), wn).reshape(shape)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [3, 8, 600, 4096])
+def test_fusion_block_cols_bounds_vmem(n):
+    """The (N, bm) fusion block is lane-aligned, at most the default
+    1024 columns, no wider than a small leaf needs, and inside the VMEM
+    budget at any cohort up to 4096 clients."""
+    bm = ops.fusion_block_cols(n, 10 ** 7)
+    assert bm % 128 == 0 and 128 <= bm <= 1024
+    assert -(-n // 8) * 8 * bm * 4 <= ops.FUSION_BLOCK_BYTES
+    assert ops.fusion_block_cols(n, 5) == 128
 
 
 @pytest.mark.parametrize("b,h,p,n", [(2, 8, 16, 32), (1, 3, 8, 8),
@@ -160,20 +172,17 @@ def test_local_step_under_vmap():
 
 
 def test_pallas_interpret_reads_env_per_call(monkeypatch):
-    """Regression: the interpret/compile switch used to be frozen into a
-    module constant at import time, so flipping REPRO_PALLAS_COMPILE
-    after `import repro.kernels.ops` silently did nothing. The switch
-    must be re-read per call."""
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
-    assert ops.pallas_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert ops.pallas_interpret() is False
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
-    assert ops.pallas_interpret() is True
-    # fusion's default_use_kernel shares THE single copy of the rule
+    """The interpret/compile switch is the platform rule — compiled on a
+    TPU backend, interpreted on any other — resolved per call, never
+    frozen at import (a module constant would keep a CPU answer after
+    the backend changes). fusion's default_use_kernel shares the rule."""
     from repro.core import fusion
     monkeypatch.delenv("REPRO_FUSION_KERNEL", raising=False)
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
+    assert ops.pallas_interpret() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.pallas_interpret() is False
     assert fusion.default_use_kernel() is True
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
-    assert fusion.default_use_kernel() is False
+    for other in ("cpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda o=other: o)
+        assert ops.pallas_interpret() is True
+        assert fusion.default_use_kernel() is False

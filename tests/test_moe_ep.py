@@ -47,9 +47,12 @@ print("OK", err)
 def test_ep_all_to_all_on_8_devices():
     """Real multi-shard all_to_all path (separate process: device count is
     locked at jax init)."""
+    # the child runs on 8 virtual CPU devices and must never reach for a
+    # chip, which the parent process may hold
     out = subprocess.run([sys.executable, "-c", _SUBPROC], env={
         "PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
         **{k: v for k, v in __import__("os").environ.items()
            if k not in ("XLA_FLAGS",)},
+        "JAX_PLATFORMS": "cpu",
     }, capture_output=True, text=True, timeout=300, cwd=".")
     assert "OK" in out.stdout, out.stdout + out.stderr
