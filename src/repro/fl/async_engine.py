@@ -283,6 +283,7 @@ def make_async_engine(task, cfg, params_like: PyTree, *, mesh=None,
                         local_unroll=resolve_local_unroll(cfg, steps))
     meth.check(ctx)
 
+    @jax.named_scope("local")
     def local_phase(global_params, batches):
         stacked = fusion_lib.broadcast_global(global_params, C)
         if mesh is not None:
@@ -304,9 +305,11 @@ def make_async_engine(task, cfg, params_like: PyTree, *, mesh=None,
                 stacked, jax.tree_util.tree_map(
                     lambda l: _client_sharding(mesh, l.ndim), stacked))
         ctx_r = dataclasses.replace(ctx, weights=weights)
-        fused = meth.fuse(stacked, global_params, ctx_r)
-        return meth.server_update(server_state, (), (), global_params,
-                                  fused, ctx_r)
+        with jax.named_scope("fuse"):
+            fused = meth.fuse(stacked, global_params, ctx_r)
+        with jax.named_scope("server"):
+            return meth.server_update(server_state, (), (), global_params,
+                                      fused, ctx_r)
 
     return AsyncEngine(cohort_size=C, buffer_k=K, mesh=mesh, method=meth,
                        local_fn=jax.jit(local_phase),
@@ -421,10 +424,11 @@ class AsyncFederation:
         C = self.engine.cohort_size
         while len(self.pending) < C:
             if not self.wave_queue:
-                ids = self.sampler.sample(self.wave_idx,
-                                          self.cfg.population, C,
-                                          self.rng,
-                                          weights=self.pop.weights)
+                with jax.profiler.TraceAnnotation("fl.sample"):
+                    ids = self.sampler.sample(self.wave_idx,
+                                              self.cfg.population, C,
+                                              self.rng,
+                                              weights=self.pop.weights)
                 self.wave_queue = [int(i) for i in ids]
                 self.wave_idx += 1
             client = self.wave_queue.pop(0)
@@ -457,7 +461,8 @@ class AsyncFederation:
                 uniform_weights=self.uniform_weights)
             gp_v = (global_params if v == self.version
                     else self.old_globals[v])
-            stacked = self.engine.local_fn(gp_v, batches)
+            with jax.profiler.TraceAnnotation("fl.dispatch"):
+                stacked = self.engine.local_fn(gp_v, batches)
             self.local_tiles += 1
             for i, d in enumerate(group):
                 d.update = jax.tree_util.tree_map(
@@ -471,11 +476,13 @@ class AsyncFederation:
         staleness = [self.version - d.version for d in self.buffer]
         w_eff = effective_weights([d.weight for d in self.buffer],
                                   staleness, self.policy)
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *[d.update for d in self.buffer])
-        server_state, new_global = self.engine.event_fn(
-            server_state, global_params, stacked,
-            jnp.asarray(w_eff, jnp.float32))
+        with jax.profiler.TraceAnnotation("fl.stack"):
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *[d.update for d in self.buffer])
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            server_state, new_global = self.engine.event_fn(
+                server_state, global_params, stacked,
+                jnp.asarray(w_eff, jnp.float32))
         self.fused_seqs.append([d.seq for d in self.buffer])
         self.events.append({
             "version": self.version,
@@ -515,10 +522,12 @@ class AsyncFederation:
                                            len(self.buffer))
                 self.free_at.append(d.t_finish)
                 if len(self.buffer) == self.engine.buffer_k:
-                    server_state, global_params = self._fuse(
-                        server_state, global_params)
-                    if on_event is not None:
-                        on_event(self.events[-1], global_params)
+                    with jax.profiler.TraceAnnotation(
+                            "fl.event", buffer=self.engine.buffer_k):
+                        server_state, global_params = self._fuse(
+                            server_state, global_params)
+                        if on_event is not None:
+                            on_event(self.events[-1], global_params)
                     if self.version >= self.cfg.rounds:
                         break
         return server_state, global_params
@@ -596,20 +605,31 @@ def run_async_federated(task, cfg, parts, get_batch, test_batches, *,
     t0 = time.time()
 
     def on_event(rec, gp):
+        # wall: as in the sync loop, stamped after the wait on the eval
+        # result where ``log`` asks for it, else at dispatch
         if eval_engine is not None:
-            c = eval_engine.run(gp, eval_tiles)
+            with jax.profiler.TraceAnnotation("fl.eval",
+                                              tiles=eval_tiles.n_tiles):
+                c = eval_engine.run(gp, eval_tiles)
         else:
-            c = evaluation_lib.host_loop_eval(eval_fn, gp, test_batches)
+            with jax.profiler.TraceAnnotation("fl.eval",
+                                              tiles=len(test_batches)):
+                c = evaluation_lib.host_loop_eval(eval_fn, gp,
+                                                  test_batches)
         counts.append(c)
         history["round"].append(rec["version"])
         history["participants"].append(rec["participants"])
         history["staleness"].append(list(rec["staleness"]))
         history["sim_time"].append(float(rec["sim_time"]))
+        if log:
+            with jax.profiler.TraceAnnotation("fl.wait"):
+                acc = _count_acc(c)
         history["wall"].append(time.time() - t0)
         if log:
-            log(f"event {rec['version']:3d} acc {_count_acc(c):.4f} "
-                f"staleness {rec['staleness']} "
-                f"t_sim {rec['sim_time']:.2f}")
+            with jax.profiler.TraceAnnotation("fl.log"):
+                log(f"event {rec['version']:3d} acc {acc:.4f} "
+                    f"staleness {rec['staleness']} "
+                    f"t_sim {rec['sim_time']:.2f}")
 
     server_state, global_params = driver.run(server_state, global_params,
                                              on_event=on_event)

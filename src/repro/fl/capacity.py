@@ -552,18 +552,20 @@ def run_tiered_round(tiered: TieredEngine, pop, method, server_state,
         _, w, gw, batches = pad_tile_inputs(
             pop, tids, tile.width, get_batch, n_steps, cfg.batch_size,
             rng, uniform_weights=uniform_weights, gw_cols=kept)
-        tier_global = tile.extract_fn(global_params)
-        _, fuse_out = tile.engine.run_tile(
-            (), server_state, tier_global, batches, weights=w,
-            group_weights=gw if tiered.use_gw else None)
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            tier_global = tile.extract_fn(global_params)
+            _, fuse_out = tile.engine.run_tile(
+                (), server_state, tier_global, batches, weights=w,
+                group_weights=gw if tiered.use_gw else None)
         means.append(fuse_out)
         w_masses.append(jnp.float32(w.sum()))
         g_masses.append(jnp.asarray(
             gw.sum(axis=0) if (tiered.use_gw and gw is not None)
             else np.zeros(kept), jnp.float32))
-    fused = tiered.combine_fn(global_params, tuple(means),
-                              tuple(w_masses), tuple(g_masses))
-    return tiered.full.finish_round(server_state, global_params, fused)
+    with jax.profiler.TraceAnnotation("fl.dispatch"):
+        fused = tiered.combine_fn(global_params, tuple(means),
+                                  tuple(w_masses), tuple(g_masses))
+        return tiered.full.finish_round(server_state, global_params, fused)
 
 
 # ---------------------------------------------------------------------------

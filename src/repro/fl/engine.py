@@ -423,43 +423,46 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
         params through the uplink encode/decode against the round's
         global BEFORE any robust pre-step — the server defends against
         what it actually received."""
-        stacked = fusion_lib.broadcast_global(global_params, n)
-        if mesh is not None:
-            constrain = lambda t: jax.lax.with_sharding_constraint(  # noqa: E731
-                t, jax.tree_util.tree_map(
-                    lambda l: _client_sharding(mesh, l.ndim), t))
-            stacked = constrain(stacked)
-            clients_state = constrain(clients_state)
-        gp_local = global_params
-        if cdtype is not None:
-            stacked = _to_compute(stacked)
-            batches = _to_compute(batches)
-            gp_local = _to_compute(global_params)
-        if attack is not None and malicious is not None:
-            row, key = malicious
-            keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-                key, jnp.arange(n))
+        with jax.named_scope("local"):
+            stacked = fusion_lib.broadcast_global(global_params, n)
+            if mesh is not None:
+                constrain = lambda t: jax.lax.with_sharding_constraint(  # noqa: E731
+                    t, jax.tree_util.tree_map(
+                        lambda l: _client_sharding(mesh, l.ndim), t))
+                stacked = constrain(stacked)
+                clients_state = constrain(clients_state)
+            gp_local = global_params
+            if cdtype is not None:
+                stacked = _to_compute(stacked)
+                batches = _to_compute(batches)
+                gp_local = _to_compute(global_params)
+            if attack is not None and malicious is not None:
+                row, key = malicious
+                keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                    key, jnp.arange(n))
 
-            def one(p, b, cs, m, k):
-                p2, cs2 = meth.client_update(p, b, gp_local, cs,
-                                             server_state, ctx_r)
-                return attack.poison_update(p2, global_params, m, k), cs2
+                def one(p, b, cs, m, k):
+                    p2, cs2 = meth.client_update(p, b, gp_local, cs,
+                                                 server_state, ctx_r)
+                    return attack.poison_update(p2, global_params, m, k), cs2
 
-            stacked, new_clients = jax.vmap(one, in_axes=(0, 0, 0, 0, 0))(
-                stacked, batches, clients_state, row, keys)
-        else:
-            stacked, new_clients = jax.vmap(
-                lambda p, b, cs: meth.client_update(
-                    p, b, gp_local, cs, server_state, ctx_r),
-                in_axes=(0, 0, 0))(stacked, batches, clients_state)
-        if cdtype is not None:
-            stacked = jax.tree_util.tree_map(
-                lambda l, g: l.astype(g.dtype), stacked, global_params)
+                stacked, new_clients = jax.vmap(one, in_axes=(0, 0, 0, 0, 0))(
+                    stacked, batches, clients_state, row, keys)
+            else:
+                stacked, new_clients = jax.vmap(
+                    lambda p, b, cs: meth.client_update(
+                        p, b, gp_local, cs, server_state, ctx_r),
+                    in_axes=(0, 0, 0))(stacked, batches, clients_state)
+            if cdtype is not None:
+                stacked = jax.tree_util.tree_map(
+                    lambda l, g: l.astype(g.dtype), stacked, global_params)
         if codec is not None:
-            stacked = codec.roundtrip(stacked, global_params)
-        if rule is not None and rule.has_pre:
-            stacked = rule.pre(stacked, global_params)
-        fused = meth.fuse(stacked, global_params, ctx_r)
+            with jax.named_scope("codec"):
+                stacked = codec.roundtrip(stacked, global_params)
+        with jax.named_scope("fuse"):
+            if rule is not None and rule.has_pre:
+                stacked = rule.pre(stacked, global_params)
+            fused = meth.fuse(stacked, global_params, ctx_r)
         return new_clients, fused
 
     def round_fn(state, global_params, batches, weights, group_weights,
@@ -472,9 +475,10 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
         if meth.host_fusion:
             return {"server": state["server"],
                     "clients": new_clients}, fused
-        new_server, new_global = meth.server_update(
-            state["server"], state["clients"], new_clients, global_params,
-            fused, ctx_r)
+        with jax.named_scope("server"):
+            new_server, new_global = meth.server_update(
+                state["server"], state["clients"], new_clients,
+                global_params, fused, ctx_r)
         return {"server": new_server, "clients": new_clients}, new_global
 
     def tile_fn(clients_state, server_state, global_params, batches,
@@ -487,8 +491,9 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
     def server_fn(server_state, global_params, fused):
         # tiled rounds: the server step sees no client states (methods
         # that read them declare cohort_tiling = False and never get here)
-        return meth.server_update(server_state, (), (), global_params,
-                                  fused, ctx)
+        with jax.named_scope("server"):
+            return meth.server_update(server_state, (), (), global_params,
+                                      fused, ctx)
 
     host_fuse = None
     if meth.host_fusion:

@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import numpy as np
 
 PyTree = Any
@@ -115,13 +116,17 @@ class Population:
         store.offload_aux(self)
 
     def gather(self, method, ids) -> PyTree:
-        """Sampled clients' state rows -> cohort-slot stacked trees."""
-        return method.gather_client_state(self.store, np.asarray(ids))
+        """Sampled clients' state rows -> cohort-slot stacked trees (the
+        ``fl.gather`` span of ``runtime.SPANS``)."""
+        with jax.profiler.TraceAnnotation("fl.gather", clients=len(ids)):
+            return method.gather_client_state(self.store, np.asarray(ids))
 
     def scatter(self, method, ids, new_states) -> None:
-        """Write cohort slots back to the sampled clients' rows."""
-        method.scatter_client_state(self.store, np.asarray(ids),
-                                    new_states)
+        """Write cohort slots back to the sampled clients' rows (the
+        ``fl.scatter`` span)."""
+        with jax.profiler.TraceAnnotation("fl.scatter", clients=len(ids)):
+            method.scatter_client_state(self.store, np.asarray(ids),
+                                        new_states)
 
 
 # ---------------------------------------------------------------------------

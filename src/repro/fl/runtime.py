@@ -66,6 +66,47 @@ from repro.fl.population import Population
 
 PyTree = Any
 
+SPANS = ("fl.round", "fl.sample", "fl.pack", "fl.load", "fl.stack",
+         "fl.gather", "fl.scatter", "fl.dispatch", "fl.eval", "fl.wait",
+         "fl.log", "fl.checkpoint", "fl.event")
+"""The program's host spans: ``jax.profiler.TraceAnnotation`` events on
+the host plane of the profiler's trace, on the same clock as the device
+planes, so that every idle gap of the device can be set beside what the
+host was doing. With no profiler running a span records nothing and its
+stats are never formatted; the stats are values the code already holds.
+
+- ``fl.round``: one iteration of the sync round loop, from sampling
+  through ``log`` (a ``StepTraceAnnotation``, ``step_num`` = the round);
+  stats ``participants``, ``tiles`` (engine tiles: participants over the
+  cohort width, rounded up, or the capacity tiers).
+- ``fl.sample``: the sampler's draw of the round's (or async wave's) ids.
+- ``fl.pack``: one engine tile's inputs (``pad_tile_inputs``: padding,
+  weights, packed batches); stats ``clients``, ``steps``, ``batch``.
+- ``fl.load``: one ``get_batch`` call inside ``fl.pack``.
+- ``fl.stack``: a ``jnp.stack`` tree-map of batches (per client, then
+  over the tile) or of an async buffer: the stack dispatches and the
+  host-to-device copies.
+- ``fl.gather``, ``fl.scatter``: client state rows to cohort slots and
+  back (``Population.gather``/``scatter``); stat ``clients``. The
+  whole-population shortcut keeps state on the device and has neither.
+- ``fl.dispatch``: a call into the compiled round programs
+  (``run_round``, ``run_tile``, ``finish_round``, ``host_fuse``, the
+  tiers' combine, the async ``local_fn``/``event_fn``).
+- ``fl.eval``: the eval dispatch (``EvalEngine.run`` or
+  ``host_loop_eval``); stat ``tiles``.
+- ``fl.wait``: the host blocking on the round's eval result (only where
+  ``log`` is given: the loop waits nowhere else).
+- ``fl.log``: the ``log`` callback.
+- ``fl.checkpoint``: ``save_fl_checkpoint``.
+- ``fl.event``: one async fusion event (fl/async_engine.py), the buffer
+  stack and dispatch through its eval and ``log``; stat ``buffer``.
+
+Inside the compiled round (fl/engine.py, fl/async_engine.py) the
+``jax.named_scope`` scopes ``local`` (broadcast and the vmapped local
+phase), ``codec``, ``fuse`` (robust pre-step and the method's fuse) and
+``server`` name the ops on the device, and the Pallas kernels are named
+``paired_fusion`` and ``local_step``."""
+
 
 @dataclasses.dataclass(frozen=True)
 class FLConfig:
@@ -325,11 +366,15 @@ def _pack_client_batches(parts, get_batch, n_steps, batch_size, rng,
             else:
                 sel = rng.choice(idx, size=batch_size,
                                  replace=len(idx) < batch_size)
-            b = get_batch(sel)
+            with jax.profiler.TraceAnnotation("fl.load"):
+                b = get_batch(sel)
             steps.append(b if hook is None else hook(b))
-        per_client.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *steps))
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_client)
+        with jax.profiler.TraceAnnotation("fl.stack"):
+            per_client.append(jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *steps))
+    with jax.profiler.TraceAnnotation("fl.stack"):
+        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                      *per_client)
 
 
 def pad_tile_inputs(pop: Population, tids, width: int, get_batch, n_steps,
@@ -341,25 +386,27 @@ def pad_tile_inputs(pop: Population, tids, width: int, get_batch, n_steps,
     (here) and the per-tier tiles (fl/capacity.py). gw_cols restricts
     the presence rows to the first K group columns (a tier that dropped
     the rest). Returns (padded_ids, weights, group_weights, batches)."""
-    tids = np.asarray(tids, np.int64)
-    n_real = len(tids)
-    padded = np.concatenate(
-        [tids, np.full(width - n_real, tids[0], np.int64)])
-    w = (np.ones(width) if uniform_weights
-         else pop.weights[padded].copy())
-    w[n_real:] = 0.0
-    gw = None
-    if pop.group_weights is not None:
-        gw = pop.group_weights[padded]
-        gw = (gw if gw_cols is None else gw[:, :gw_cols]).copy()
-        gw[n_real:] = 0.0
-    pois = None
-    if pop.poison is not None and pop.malicious is not None:
-        pois = [pop.poison if pop.malicious[i] else None for i in padded]
-    batches = _pack_client_batches([pop.parts[i] for i in padded],
-                                   get_batch, n_steps, batch_size, rng,
-                                   poison_fns=pois)
-    return padded, w, gw, batches
+    with jax.profiler.TraceAnnotation("fl.pack", clients=width,
+                                      steps=n_steps, batch=batch_size):
+        tids = np.asarray(tids, np.int64)
+        n_real = len(tids)
+        padded = np.concatenate(
+            [tids, np.full(width - n_real, tids[0], np.int64)])
+        w = (np.ones(width) if uniform_weights
+             else pop.weights[padded].copy())
+        w[n_real:] = 0.0
+        gw = None
+        if pop.group_weights is not None:
+            gw = pop.group_weights[padded]
+            gw = (gw if gw_cols is None else gw[:, :gw_cols]).copy()
+            gw[n_real:] = 0.0
+        pois = None
+        if pop.poison is not None and pop.malicious is not None:
+            pois = [pop.poison if pop.malicious[i] else None for i in padded]
+        batches = _pack_client_batches([pop.parts[i] for i in padded],
+                                       get_batch, n_steps, batch_size, rng,
+                                       poison_fns=pois)
+        return padded, w, gw, batches
 
 
 def _malicious_inputs(engine, pop: Population, padded, n_real, cfg,
@@ -413,9 +460,10 @@ def run_sampled_round(engine, pop: Population, method, server_state,
         state = {"server": server_state,
                  "clients": (pop.clients if whole
                              else pop.gather(method, ids))}
-        state, new_global = engine.run_round(state, global_params, batches,
-                                             weights=w, group_weights=gw,
-                                             malicious=mal)
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            state, new_global = engine.run_round(
+                state, global_params, batches, weights=w, group_weights=gw,
+                malicious=mal)
         if whole:
             pop.clients = state["clients"]
         else:
@@ -475,11 +523,10 @@ def run_sampled_round(engine, pop: Population, method, server_state,
         mal = _malicious_inputs(engine, pop, padded, n_real, cfg,
                                 round_idx)
         cstate = pop.gather(method, padded)
-        new_cstate, fuse_out = engine.run_tile(cstate, server_state,
-                                               global_params, batches,
-                                               weights=w,
-                                               group_weights=gw,
-                                               malicious=mal)
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            new_cstate, fuse_out = engine.run_tile(
+                cstate, server_state, global_params, batches, weights=w,
+                group_weights=gw, malicious=mal)
         pop.scatter(method, tids, jax.tree_util.tree_map(
             lambda a: a[:n_real], new_cstate))
         if method.host_fusion:
@@ -496,9 +543,11 @@ def run_sampled_round(engine, pop: Population, method, server_state,
             lambda *xs: jnp.concatenate(xs, axis=0), *stacked_tiles)
         w_all = (np.ones(len(ids)) if uniform_weights
                  else pop.weights[ids])
-        return server_state, engine.host_fuse(stacked, w_all)
+        with jax.profiler.TraceAnnotation("fl.dispatch"):
+            return server_state, engine.host_fuse(stacked, w_all)
     fused = jax.tree_util.tree_map(lambda l: l / w_acc, acc)
-    return engine.finish_round(server_state, global_params, fused)
+    with jax.profiler.TraceAnnotation("fl.dispatch"):
+        return engine.finish_round(server_state, global_params, fused)
 
 
 def one_shot_config(cfg: FLConfig) -> FLConfig:
@@ -550,11 +599,14 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
     set; without ``predict_fn`` the seed per-batch host loop
     (``evaluation.host_loop_eval``) supplies the mean-of-batch
     accuracies as before. ``participants`` records the sampled client
-    ids per round. Per-round ``wall`` entries are host DISPATCH
-    timestamps (rounds execute asynchronously unless ``log`` forces a
-    sync — client-stateful methods under PARTIAL participation also sync
-    on the per-round state scatter); ``wall_total`` is the true
-    end-to-end time including the final materialization.
+    ids per round. Per-round ``wall`` entries are seconds from the start
+    of the loop: with ``log`` given, stamped once the host holds the
+    round's eval result (the loop's per-round sync); without it, host
+    DISPATCH timestamps (rounds then execute asynchronously —
+    client-stateful methods under PARTIAL participation still sync on the
+    per-round state scatter). ``wall_total`` is the true end-to-end time
+    including the final materialization. The loop writes the host spans
+    of ``SPANS`` into a running profiler's trace.
 
     ``cfg.tiers`` routes the rounds through the heterogeneous-capacity
     engine (fl/capacity.py, DESIGN.md §11): one compiled tile per tier,
@@ -706,47 +758,68 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
     def eval_and_record(r, participants):
         """Evaluate the current global and append one history row — the
         single shape of a per-round record (the round loop and the
-        already-complete resume tail must agree)."""
+        already-complete resume tail must agree). With ``log`` given the
+        host waits here for the result, and returns its accuracy, before
+        ``wall`` is stamped; otherwise ``wall`` is the dispatch time."""
         if eval_engine is not None:
-            c = eval_engine.run(global_params, eval_tiles)
+            with jax.profiler.TraceAnnotation("fl.eval",
+                                              tiles=eval_tiles.n_tiles):
+                c = eval_engine.run(global_params, eval_tiles)
         else:
-            c = evaluation_lib.host_loop_eval(engine.eval_fn,
-                                              global_params, test_batches)
+            with jax.profiler.TraceAnnotation("fl.eval",
+                                              tiles=len(test_batches)):
+                c = evaluation_lib.host_loop_eval(
+                    engine.eval_fn, global_params, test_batches)
         counts.append(c)
         history["round"].append(r)
         history["participants"].append(participants)
+        acc = None
+        if log:                    # logging opts into the per-round sync
+            with jax.profiler.TraceAnnotation("fl.wait"):
+                acc = _count_acc(c)
         history["wall"].append(time.time() - t0)
-        return c
+        return acc
 
     for r in range(start_round, cfg.rounds):
-        ids = sampler.sample(r, cfg.population, cfg.cohort_size, rng,
-                             weights=pop.weights)
-        if tiered is not None:
-            from repro.fl.capacity import run_tiered_round
-            server_state, global_params = run_tiered_round(
-                tiered, pop, method, server_state, global_params, ids,
-                get_batch, n_steps, cfg, rng, uniform_weights=uniform_w)
-        else:
-            server_state, global_params = run_sampled_round(
-                engine, pop, method, server_state, global_params, ids,
-                get_batch, n_steps, cfg, rng, uniform_weights=uniform_w,
-                round_idx=r)
-        if checkpoint_dir and ((r + 1) % checkpoint_every == 0
-                               or r == cfg.rounds - 1):
-            from repro.checkpoint import io as ckpt_io
-            ckpt_io.save_fl_checkpoint(
-                checkpoint_dir, round_idx=r + 1,
-                global_params=global_params, server_state=server_state,
-                client_state=pop.store, rng=rng)
-        if len(ids) == cfg.population:
-            if full_ids is None:
-                full_ids = np.asarray(ids)
-            participants = full_ids
-        else:
-            participants = np.asarray(ids)
-        c = eval_and_record(r, participants)
-        if log:                    # logging opts into the per-round sync
-            log(f"round {r:3d} acc {_count_acc(c):.4f}")
+        with jax.profiler.StepTraceAnnotation("fl.round",
+                                              step_num=r) as round_span:
+            with jax.profiler.TraceAnnotation("fl.sample"):
+                ids = sampler.sample(r, cfg.population, cfg.cohort_size,
+                                     rng, weights=pop.weights)
+            if round_span.is_enabled():
+                round_span.set_metadata(
+                    participants=len(ids),
+                    tiles=(len(tiered.tiles) if tiered is not None
+                           else -(-len(ids) // cfg.cohort_size)))
+            if tiered is not None:
+                from repro.fl.capacity import run_tiered_round
+                server_state, global_params = run_tiered_round(
+                    tiered, pop, method, server_state, global_params, ids,
+                    get_batch, n_steps, cfg, rng, uniform_weights=uniform_w)
+            else:
+                server_state, global_params = run_sampled_round(
+                    engine, pop, method, server_state, global_params, ids,
+                    get_batch, n_steps, cfg, rng, uniform_weights=uniform_w,
+                    round_idx=r)
+            if checkpoint_dir and ((r + 1) % checkpoint_every == 0
+                                   or r == cfg.rounds - 1):
+                from repro.checkpoint import io as ckpt_io
+                with jax.profiler.TraceAnnotation("fl.checkpoint"):
+                    ckpt_io.save_fl_checkpoint(
+                        checkpoint_dir, round_idx=r + 1,
+                        global_params=global_params,
+                        server_state=server_state, client_state=pop.store,
+                        rng=rng)
+            if len(ids) == cfg.population:
+                if full_ids is None:
+                    full_ids = np.asarray(ids)
+                participants = full_ids
+            else:
+                participants = np.asarray(ids)
+            acc = eval_and_record(r, participants)
+            if log:
+                with jax.profiler.TraceAnnotation("fl.log"):
+                    log(f"round {r:3d} acc {acc:.4f}")
     if already_complete:
         # resuming a finished run: nothing to train, but callers index
         # h["acc"][-1] — report one eval of the restored model instead

@@ -52,4 +52,5 @@ def local_step_kernel(p, v, g, *, lr: float, mu: float, bm: int = 1024,
         out_shape=[jax.ShapeDtypeStruct((1, m), p.dtype),
                    jax.ShapeDtypeStruct((1, m), v.dtype)],
         interpret=interpret,
+        name="local_step",
     )(p, v, g)

@@ -46,4 +46,5 @@ def paired_fusion_kernel(stacked, weights, *, bm: int = 1024,
         out_specs=pl.BlockSpec((1, bm), lambda mi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((1, m), stacked.dtype),
         interpret=interpret,
+        name="paired_fusion",
     )(stacked, w2)

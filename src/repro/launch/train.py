@@ -26,6 +26,7 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -156,9 +157,11 @@ def run_fl(args):
         return recs
 
     task, fl, parts, get_batch, test_batches = build_fl_run(args)
-    h = run_federated(task, fl, parts, get_batch, test_batches,
-                      latency=args.latency, log=print,
-                      use_local_kernel=args.use_local_kernel)
+    with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+          else contextlib.nullcontext()):
+        h = run_federated(task, fl, parts, get_batch, test_batches,
+                          latency=args.latency, log=print,
+                          use_local_kernel=args.use_local_kernel)
     print("final acc:", h["acc"][-1])
     return h
 
@@ -281,6 +284,10 @@ def parse_args(argv=None):
     ap.add_argument("--noise", type=float, default=1.2)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt", default="")
+    ap.add_argument("--profile-dir", default="",
+                    help="fl mode: write a profiler trace of the run here "
+                         "(the program's fl.* host spans and device "
+                         "scopes, repro.fl.runtime.SPANS)")
     ap.add_argument("--dry-run", action="store_true",
                     help="fl mode: lower+compile one engine round (reduced "
                          "vgg9, chosen --method) on the host mesh instead "
@@ -309,6 +316,10 @@ def parse_args(argv=None):
                  "--use-local-kernel are only supported with --mode fl")
     if args.mode != "fl" and args.alignment != "grouped":
         ap.error("--alignment is only supported with --mode fl")
+    if args.profile_dir and (args.mode != "fl" or args.scenario
+                             or args.dry_run):
+        ap.error("--profile-dir traces an fl-mode run: not with --mode lm, "
+                 "--scenario or --dry-run")
     return args
 
 

@@ -55,6 +55,26 @@ def test_train_main_takes_argv_and_returns_history(monkeypatch, tmp_path):
     assert "final_params" in h
 
 
+def test_profile_dir_traces_the_run(monkeypatch, tmp_path):
+    """``--profile-dir`` writes a trace holding the program's spans."""
+    from jax.profiler import ProfileData
+
+    from repro.fl.runtime import SPANS
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    out = tmp_path / "profile"
+    train.main(["--mode", "fl", "--arch", "vgg9", "--method", "fed2",
+                "--reduced", "--rounds", "2", "--nodes", "2",
+                "--steps-per-epoch", "1", "--batch", "4",
+                "--train-size", "40", "--profile-dir", str(out)])
+    path, = out.glob("plugins/profile/*/*.xplane.pb")
+    names = [ev.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name in SPANS]
+    assert names.count("fl.round") == 2 and names.count("fl.pack") == 2
+    with pytest.raises(SystemExit):
+        train.parse_args(["--mode", "lm", "--profile-dir", str(out)])
+
+
 class _FourDeviceMesh:
     size = 4
 

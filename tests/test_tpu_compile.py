@@ -8,6 +8,8 @@ collecting this file never loads the TPU library; the tests skip where it
 cannot be described. The kernels are called with ``interpret=False``
 directly, because on a CPU backend ``ops.pallas_interpret()`` is True.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -88,6 +90,8 @@ def test_paired_fusion_compiles_for_v5e(one_chip, no_compile_cache,
         lambda a, b: paired_fusion_kernel(a, b, bm=bm, interpret=False),
         x, w)
     assert "tpu_custom_call" in hlo
+    # the kernel's own name, which the benchmark's trace readers match
+    assert re.search(r"%paired_fusion(\.\d+)? = ", hlo)
 
 
 @pytest.mark.parametrize("cohort", [None, 8])
@@ -108,4 +112,6 @@ def test_local_step_compiles_for_v5e(one_chip, no_compile_cache, cohort):
         shape = (cohort,) + shape
         step = jax.vmap(step)
     spec = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    assert "tpu_custom_call" in _compiled_text(step, spec, spec, spec)
+    hlo = _compiled_text(step, spec, spec, spec)
+    assert "tpu_custom_call" in hlo
+    assert re.search(r"%local_step(\.\d+)? = ", hlo)
