@@ -311,8 +311,6 @@ def run_scenario(spec: ScenarioSpec, *, mesh=None, use_kernel=None,
     """Execute one scenario end to end (partition -> run_federated ->
     per-class/per-group accuracy rows) and optionally serialize the
     record to ``<outdir>/scenario_<name>.json``."""
-    import jax.numpy as jnp
-
     from repro.data.synthetic import make_image_dataset
     from repro.fl import evaluation as evaluation_lib
     from repro.fl.runtime import cnn_task, run_federated
@@ -324,8 +322,7 @@ def run_scenario(spec: ScenarioSpec, *, mesh=None, use_kernel=None,
     parts = spec.partition(ds.labels)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": test.images, "labels": test.labels}]
     task = cnn_task(spec.model_config())

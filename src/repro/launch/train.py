@@ -105,8 +105,7 @@ def build_fl_run(args):
                               cfg.n_classes, seed=args.seed)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]

@@ -291,31 +291,44 @@ def _idx_batch(sel):
     return {"idx": jnp.asarray(np.asarray(sel, np.int64))}
 
 
-def test_pack_client_batches_shapes_and_membership():
+def _idx_batch_host(sel):
+    """The identity batch as host arrays (the packer's host path)."""
+    return {"idx": np.asarray(sel, np.int64)}
+
+
+_IDX_LOADERS = pytest.mark.parametrize(
+    "idx_batch", [_idx_batch, _idx_batch_host], ids=["device", "host"])
+
+
+@_IDX_LOADERS
+def test_pack_client_batches_shapes_and_membership(idx_batch):
     parts = [np.array([0, 1, 2, 3, 4]), np.array([10, 11, 12])]
-    out = _pack_client_batches(parts, _idx_batch, n_steps=3, batch_size=2,
+    out = _pack_client_batches(parts, idx_batch, n_steps=3, batch_size=2,
                                rng=np.random.default_rng(0))
     assert out["idx"].shape == (2, 3, 2)          # (N, steps, B)
     for c, part in enumerate(parts):
         assert set(np.asarray(out["idx"][c]).ravel()) <= set(part)
 
 
-def test_pack_client_batches_empty_shard_selects_index_zero():
+@_IDX_LOADERS
+def test_pack_client_batches_empty_shard_selects_index_zero(idx_batch):
     """An empty client shard must still produce full-shape batches
     (index 0 placeholders) so the vmapped round never sees ragged data."""
     parts = [np.array([], np.int64), np.array([5, 6, 7, 8])]
-    out = _pack_client_batches(parts, _idx_batch, n_steps=2, batch_size=3,
+    out = _pack_client_batches(parts, idx_batch, n_steps=2, batch_size=3,
                                rng=np.random.default_rng(0))
     assert out["idx"].shape == (2, 2, 3)
     np.testing.assert_array_equal(np.asarray(out["idx"][0]),
                                   np.zeros((2, 3), np.int64))
 
 
-def test_pack_client_batches_short_shard_samples_with_replacement():
+@_IDX_LOADERS
+def test_pack_client_batches_short_shard_samples_with_replacement(
+        idx_batch):
     """A shard shorter than the batch size samples WITH replacement —
     every batch is full and draws only from the client's own shard."""
     parts = [np.array([41, 42])]                   # shard < batch_size
-    out = _pack_client_batches(parts, _idx_batch, n_steps=2, batch_size=5,
+    out = _pack_client_batches(parts, idx_batch, n_steps=2, batch_size=5,
                                rng=np.random.default_rng(0))
     got = np.asarray(out["idx"][0])
     assert got.shape == (2, 5)
@@ -324,17 +337,70 @@ def test_pack_client_batches_short_shard_samples_with_replacement():
     assert any(len(np.unique(row)) < len(row) for row in got)
 
 
-def test_pack_client_batches_deterministic_under_fixed_seed():
+@_IDX_LOADERS
+def test_pack_client_batches_deterministic_under_fixed_seed(idx_batch):
     parts = [np.arange(20), np.arange(30, 50), np.array([7])]
-    a = _pack_client_batches(parts, _idx_batch, n_steps=4, batch_size=6,
+    a = _pack_client_batches(parts, idx_batch, n_steps=4, batch_size=6,
                              rng=np.random.default_rng(123))
-    b = _pack_client_batches(parts, _idx_batch, n_steps=4, batch_size=6,
+    b = _pack_client_batches(parts, idx_batch, n_steps=4, batch_size=6,
                              rng=np.random.default_rng(123))
     np.testing.assert_array_equal(np.asarray(a["idx"]),
                                   np.asarray(b["idx"]))
-    c = _pack_client_batches(parts, _idx_batch, n_steps=4, batch_size=6,
+    c = _pack_client_batches(parts, idx_batch, n_steps=4, batch_size=6,
                              rng=np.random.default_rng(124))
     assert not np.array_equal(np.asarray(a["idx"]), np.asarray(c["idx"]))
+
+
+def _host_batch(sel):
+    return {"images": _DS.images[sel], "labels": _DS.labels[sel]}
+
+
+@pytest.mark.parametrize("poisoned", [False, True])
+def test_pack_client_batches_host_and_device_paths_agree(poisoned):
+    """Host leaves are packed on the host and put on the device once,
+    device leaves are stacked there: the same batches bit for bit, as
+    device arrays of the loader's dtypes, with the rng left in the same
+    state, and a label-flip hook applied alike on both paths."""
+    from repro.fl import attacks
+    flip = attacks.get("label_flip")
+    hooks = ([None, lambda b: flip.poison_batch(b, 4), None]
+             if poisoned else None)
+    parts = [np.arange(30), np.array([], np.int64), np.array([40, 41])]
+    rngs = [np.random.default_rng(5), np.random.default_rng(5)]
+    dev, host = (_pack_client_batches(parts, get, 3, 8, rng,
+                                      poison_fns=hooks)
+                 for get, rng in zip((_get_batch, _host_batch), rngs))
+    for k in ("images", "labels"):
+        assert isinstance(host[k], jax.Array)
+        assert host[k].dtype == getattr(_DS, k).dtype == dev[k].dtype
+        assert host[k].shape == (3, 3, 8) + getattr(_DS, k).shape[1:]
+        np.testing.assert_array_equal(np.asarray(host[k]),
+                                      np.asarray(dev[k]))
+    np.testing.assert_array_equal(rngs[0].integers(2**31, size=4),
+                                  rngs[1].integers(2**31, size=4))
+    honest = _pack_client_batches(parts, _host_batch, 3, 8,
+                                  np.random.default_rng(5))
+    flipped = np.asarray(host["labels"][1]) != np.asarray(
+        honest["labels"][1])
+    assert flipped.any() == poisoned
+
+
+@pytest.mark.parametrize("cohort_size", [3, 2], ids=["full", "tiled"])
+def test_host_and_device_loaders_give_the_same_run(cohort_size):
+    """A whole run with a loader of host arrays equals the run with one
+    of device arrays, bit for bit: one cohort of every client, and the
+    clients in cohort tiles."""
+    task = cnn_task(_cfg("fedavg"))
+    fl = FLConfig(population=3, cohort_size=cohort_size, rounds=2,
+                  local_epochs=1, steps_per_epoch=2, batch_size=8,
+                  lr=0.02, momentum=0.9, method="fedavg", seed=0)
+    parts = nxc_partition(_DS.labels, 3, 2, 4, seed=0)
+    dev, host = (run_federated(task, fl, parts, get, _TEST_BATCHES)
+                 for get in (_get_batch, _host_batch))
+    assert dev["acc"] == host["acc"]
+    for a, b in zip(jax.tree_util.tree_leaves(dev["final_params"]),
+                    jax.tree_util.tree_leaves(host["final_params"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

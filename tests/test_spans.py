@@ -4,8 +4,9 @@
   every client in two cohort tiles, runs under ``jax.profiler.trace``
   and is read back with ``ProfileData``: one ``fl.round`` per round with
   its ``step_num``, one ``fl.pack`` per tile with its stats, clients x
-  steps ``fl.load`` spans inside each ``fl.pack``, the wait on the eval
-  result before ``log``.
+  steps ``fl.load`` spans inside each ``fl.pack``, the ``fl.stack``
+  spans of a loader of device arrays or of host arrays, the wait on the
+  eval result before ``log``.
 - The lowered round program carries the ``local``, ``codec``, ``fuse``
   and ``server`` scopes and both kernels' names.
 - No span's stats cost anything with no profiler running: every stat is
@@ -45,6 +46,13 @@ def _get_batch(sel):
             "labels": jnp.asarray(_DS.labels[sel])}
 
 
+def _host_batch(sel):
+    return {"images": _DS.images[sel], "labels": _DS.labels[sel]}
+
+
+LOADERS = {"device": _get_batch, "host": _host_batch}
+
+
 def _fl(population=4, method="fed2", **kw):
     return FLConfig(population=population, cohort_size=COHORT, rounds=2,
                     local_epochs=1, steps_per_epoch=STEPS, batch_size=BATCH,
@@ -64,8 +72,14 @@ class _CohortThenAll(population.ClientSampler):
         return np.arange(cohort_size if round_idx == 0 else population)
 
 
+@pytest.fixture(scope="module", params=sorted(LOADERS))
+def loader(request):
+    """Which loader the traced run packs from: device or host arrays."""
+    return request.param
+
+
 @pytest.fixture(scope="module")
-def program_events(tmp_path_factory):
+def program_events(loader, tmp_path_factory):
     """The ``fl.*`` host events of the traced run, in start order, as
     (name, start, end, stats)."""
     trace_dir = str(tmp_path_factory.mktemp("trace"))
@@ -74,8 +88,8 @@ def program_events(tmp_path_factory):
                    lambda name: _CohortThenAll())
         cfg = _fl(sampler="uniform")
         with jax.profiler.trace(trace_dir):
-            run_federated(_TASK, cfg, _parts(4), _get_batch, _TEST_BATCHES,
-                          log=lambda msg: None)
+            run_federated(_TASK, cfg, _parts(4), LOADERS[loader],
+                          _TEST_BATCHES, log=lambda msg: None)
     path, = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
     events = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
               for plane in ProfileData.from_file(path).planes
@@ -98,7 +112,14 @@ def test_one_round_span_per_round(program_events):
     assert {e[0] for e in program_events} <= set(runtime.SPANS)
 
 
-def test_one_pack_per_tile_with_its_loads(program_events):
+def test_one_pack_per_tile_with_its_loads(loader, program_events):
+    """Each tile's pack holds one load a (client, step). A loader of
+    device arrays stacks each client's steps, then the tile (C + 1
+    stacks); one of host arrays fills a host buffer and puts it on the
+    device in one stack span that carries the bytes it copied."""
+    tile_bytes = COHORT * STEPS * BATCH * (
+        _DS.images.itemsize * int(np.prod(_DS.images.shape[1:]))
+        + _DS.labels.itemsize)
     rounds = [e for e in program_events if e[0] == "fl.round"]
     for r, tiles in zip(rounds, (1, 2)):
         inside = _inside(program_events, r)
@@ -112,7 +133,13 @@ def test_one_pack_per_tile_with_its_loads(program_events):
                             "batch": BATCH}
             names = [e[0] for e in _inside(program_events, p)]
             assert names.count("fl.load") == COHORT * STEPS
-            assert names.count("fl.stack") == COHORT + 1
+            stacks = [e[3] for e in _inside(program_events, p)
+                      if e[0] == "fl.stack"]
+            if loader == "host":
+                assert stacks == [{"host": 1, "bytes": tile_bytes}]
+            else:
+                assert len(stacks) == COHORT + 1
+                assert all(st["host"] == 0 for st in stacks)
 
 
 def test_wait_on_the_result_precedes_log(program_events):
