@@ -79,8 +79,7 @@ def run_case(name: str, method: str, *, arch="vgg9", nodes=6, cpn=None,
                               seed=seed)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]
@@ -137,8 +136,7 @@ def _engine_fixture(nodes, steps_per_epoch, batch):
     parts = nxc_partition(ds.labels, nodes, 5, N_CLASSES, seed=0)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     batches = _pack_client_batches(parts, get_batch, steps_per_epoch,
                                    batch, np.random.default_rng(0))
@@ -356,8 +354,7 @@ def bench_cohort(*, populations=None, cohort=8, rounds=None,
     ds, _ = dataset()
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     from repro.fl.population import Population
     from repro.fl import population as population_lib
@@ -452,8 +449,7 @@ def bench_tiers(*, population=6, rounds=None, steps_per_epoch=4,
     parts = nxc_partition(ds.labels, population, 5, N_CLASSES, seed=0)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]
@@ -589,8 +585,7 @@ def bench_async(*, population=8, cohort_size=4, buffer_k=2,
                           seed=0)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]
@@ -739,8 +734,7 @@ def bench_alignment(*, nodes=6, cpn=2, rounds=None, steps_per_epoch=6,
     parts = nxc_partition(ds.labels, nodes, cpn, N_CLASSES, seed=0)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]

@@ -54,8 +54,7 @@ def main():
     parts = nxc_partition(ds.labels, 6, 5, 10, seed=1)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     fl = FLConfig(population=6, rounds=6, local_epochs=1, steps_per_epoch=8,
                   batch_size=16, lr=0.008, momentum=0.9, method="fed2")

@@ -48,8 +48,7 @@ def main():
                           args.classes_per_node, 10, seed=1)
 
     def get_batch(sel):
-        return {"images": jnp.asarray(ds.images[sel]),
-                "labels": jnp.asarray(ds.labels[sel])}
+        return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]

@@ -51,9 +51,8 @@ def main():
 
     def get_batch(sel):
         sl = toks[sel]
-        return {"tokens": jnp.asarray(sl[:, :-1]),
-                "labels": jnp.asarray(sl[:, 1:]),
-                "mask": jnp.ones((len(sel), args.seq), jnp.float32)}
+        return {"tokens": sl[:, :-1], "labels": sl[:, 1:],
+                "mask": np.ones((len(sel), args.seq), np.float32)}
 
     test_toks, _ = make_token_dataset(64, args.seq + 1, cfg.vocab,
                                       n_domains=n_domains, seed=7)

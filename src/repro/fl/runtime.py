@@ -83,15 +83,9 @@ stats are never formatted; the stats are values the code already holds.
 - ``fl.pack``: one engine tile's inputs (``pad_tile_inputs``: padding,
   weights, packed batches); stats ``clients``, ``steps``, ``batch``.
 - ``fl.load``: one ``get_batch`` call inside ``fl.pack``.
-- ``fl.stack``: the step batches of a tile made into one device array
-  per leaf (``_pack_client_batches``), or an async buffer's stack. A
-  loader of host arrays gives one per tile, the single
-  ``jax.device_put`` of the packed host buffers; a loader of device
-  arrays gives one per client and one over the tile, the eager
-  ``jnp.stack`` dispatches. Stats ``host`` (1 when every leaf was
-  packed on the host, else 0) and ``bytes`` (bytes put from the host)
-  on the tile's span, ``host`` 0 on the per-client ones; the async
-  buffer's span has none.
+- ``fl.stack``: the single ``jax.device_put`` of a tile's packed host
+  buffers (``_pack_client_batches``; stat ``bytes``, the bytes put), or
+  an async buffer's ``jnp.stack`` (no stats).
 - ``fl.gather``, ``fl.scatter``: client state rows to cohort slots and
   back (``Population.gather``/``scatter``); stat ``clients``. The
   whole-population shortcut keeps state on the device and has neither.
@@ -363,14 +357,12 @@ def _pack_client_batches(parts, get_batch, n_steps, batch_size, rng,
     after the rng draw, so the packing rng stream is bit-identical to
     the honest run.
 
-    The path is chosen leaf by leaf from what ``get_batch`` returned. A
-    host leaf (``np.ndarray``) is copied step by step into one fresh
-    (C, n_steps, B, ...) host buffer, which goes to the device in one
-    ``jax.device_put`` after the last client; a fresh buffer per tile,
-    because a put may still be reading the last one. Any other leaf (a
-    device array) is stacked with ``jnp.stack`` per client, then over
-    the tile. Both give the same values; the host path spares a tile
-    C * n_steps small copies and C + 1 eager stacks."""
+    Each step batch, after its hook, is copied into one fresh
+    (C, n_steps, B, ...) host buffer per leaf; the buffers go to the
+    device in one ``jax.device_put`` after the last client. A loader may
+    return host or device arrays; host arrays (the launcher's) spare a
+    device-to-host copy per step. A fresh buffer per tile, because a put
+    may still be reading the last one."""
     C = len(parts)
     treedef, slots = None, None
     for ci, idx in enumerate(parts):
@@ -387,26 +379,12 @@ def _pack_client_batches(parts, get_batch, n_steps, batch_size, rng,
                 b if hook is None else hook(b))
             if slots is None:
                 slots = [np.empty((C, n_steps) + x.shape, x.dtype)
-                         if isinstance(x, np.ndarray)
-                         else [[] for _ in range(C)] for x in leaves]
-                on_device = [slot for slot in slots
-                             if isinstance(slot, list)]
+                         for x in leaves]
             for slot, x in zip(slots, leaves):
-                if isinstance(slot, np.ndarray):
-                    slot[ci, s] = x
-                else:
-                    slot[ci].append(x)
-        if on_device:
-            with jax.profiler.TraceAnnotation("fl.stack", host=0):
-                for slot in on_device:
-                    slot[ci] = jnp.stack(slot[ci])
-    on_host = [slot for slot in slots if isinstance(slot, np.ndarray)]
-    host, nbytes = int(not on_device), sum(x.nbytes for x in on_host)
-    with jax.profiler.TraceAnnotation("fl.stack", host=host, bytes=nbytes):
-        put = iter(jax.device_put(on_host))
-        leaves = [next(put) if isinstance(slot, np.ndarray)
-                  else jnp.stack(slot) for slot in slots]
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+                slot[ci, s] = x
+    nbytes = sum(x.nbytes for x in slots)
+    with jax.profiler.TraceAnnotation("fl.stack", bytes=nbytes):
+        return jax.tree_util.tree_unflatten(treedef, jax.device_put(slots))
 
 
 def pad_tile_inputs(pop: Population, tids, width: int, get_batch, n_steps,
@@ -610,10 +588,10 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
     """parts: list of cfg.population per-client index arrays;
     get_batch(sel)->batch dict, called once per (client, step) with a
     (B,) row selection; test_batches: list of batch dicts for global
-    eval. A loader that returns host arrays (``np.ndarray``) has each
-    tile packed on the host and copied to the device once; one that
-    returns device arrays has them stacked on the device as before
-    (``_pack_client_batches``). The batches are the same either way.
+    eval. Each tile's step batches are packed on the host and copied to
+    the device once (``_pack_client_batches``), so a loader of host
+    arrays (``np.ndarray``) is the cheap one; device arrays are copied
+    back to the host first and give the same batches.
 
     class_counts (population, C) + group_spec enable Eq. 19's non-IID
     refinement for group-structured methods (fed2): group g fuses only

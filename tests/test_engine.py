@@ -292,7 +292,7 @@ def _idx_batch(sel):
 
 
 def _idx_batch_host(sel):
-    """The identity batch as host arrays (the packer's host path)."""
+    """The identity batch as host arrays (as the launcher's loader)."""
     return {"idx": np.asarray(sel, np.int64)}
 
 
@@ -357,10 +357,10 @@ def _host_batch(sel):
 
 @pytest.mark.parametrize("poisoned", [False, True])
 def test_pack_client_batches_host_and_device_paths_agree(poisoned):
-    """Host leaves are packed on the host and put on the device once,
-    device leaves are stacked there: the same batches bit for bit, as
-    device arrays of the loader's dtypes, with the rng left in the same
-    state, and a label-flip hook applied alike on both paths."""
+    """A loader of host arrays and one of device arrays give the same
+    packed batches bit for bit, as device arrays of the loader's dtypes,
+    with the rng left in the same state, and a label-flip hook applied
+    alike to both."""
     from repro.fl import attacks
     flip = attacks.get("label_flip")
     hooks = ([None, lambda b: flip.poison_batch(b, 4), None]

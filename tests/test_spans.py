@@ -4,9 +4,9 @@
   every client in two cohort tiles, runs under ``jax.profiler.trace``
   and is read back with ``ProfileData``: one ``fl.round`` per round with
   its ``step_num``, one ``fl.pack`` per tile with its stats, clients x
-  steps ``fl.load`` spans inside each ``fl.pack``, the ``fl.stack``
-  spans of a loader of device arrays or of host arrays, the wait on the
-  eval result before ``log``.
+  steps ``fl.load`` spans inside each ``fl.pack`` and one ``fl.stack``
+  with the bytes it put, for a loader of device arrays or of host
+  arrays, the wait on the eval result before ``log``.
 - The lowered round program carries the ``local``, ``codec``, ``fuse``
   and ``server`` scopes and both kernels' names.
 - No span's stats cost anything with no profiler running: every stat is
@@ -112,11 +112,10 @@ def test_one_round_span_per_round(program_events):
     assert {e[0] for e in program_events} <= set(runtime.SPANS)
 
 
-def test_one_pack_per_tile_with_its_loads(loader, program_events):
-    """Each tile's pack holds one load a (client, step). A loader of
-    device arrays stacks each client's steps, then the tile (C + 1
-    stacks); one of host arrays fills a host buffer and puts it on the
-    device in one stack span that carries the bytes it copied."""
+def test_one_pack_per_tile_with_its_loads(program_events):
+    """Each tile's pack holds one load a (client, step) and, whether the
+    loader returns host or device arrays, one stack span: the single put
+    of the packed host buffers, carrying the bytes it copied."""
     tile_bytes = COHORT * STEPS * BATCH * (
         _DS.images.itemsize * int(np.prod(_DS.images.shape[1:]))
         + _DS.labels.itemsize)
@@ -135,11 +134,7 @@ def test_one_pack_per_tile_with_its_loads(loader, program_events):
             assert names.count("fl.load") == COHORT * STEPS
             stacks = [e[3] for e in _inside(program_events, p)
                       if e[0] == "fl.stack"]
-            if loader == "host":
-                assert stacks == [{"host": 1, "bytes": tile_bytes}]
-            else:
-                assert len(stacks) == COHORT + 1
-                assert all(st["host"] == 0 for st in stacks)
+            assert stacks == [{"bytes": tile_bytes}]
 
 
 def test_wait_on_the_result_precedes_log(program_events):
