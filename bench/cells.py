@@ -14,6 +14,42 @@ found by its name.
 - ``bench/limits/<workload>.json``: the limit of each number the
   correctness check compares.
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric.
+
+A model family is two modules, and the harness (``bench/run.py``,
+``bench/check.py``, ``bench/faults.py``) reads nothing else of it, so a
+configuration of a new family is new files only.
+
+``bench/reference/<family>.py``, the plain reference and the family's
+side of the harness:
+
+- ``run_rounds(model, seed, rounds, test, fetch, *, lr, momentum,
+  devices, block, precision)``: the federated rounds from the seed's
+  initialisation, ``rounds`` one dict per checked round with ``sels``
+  (P, S, B), each participant's training rows, and ``weights`` (P,);
+  clients vmapped in blocks of at most ``block`` a device (the engine's
+  width over the cell's chips), the mean taken as a round of such tiles
+  takes it; ``precision`` ``"highest"``, or ``"high"`` for the control. Returns
+  ``thetas``, the global before the first round and after each, and the
+  reference's eval after each under the key the program's history uses
+  for it, so that ``program_eval`` reads either;
+- ``batch_shapes(batch) -> dict``: the traffic file's ``expect`` entries
+  that one loader batch fixes, beside the generic ones (population,
+  cohort, steps, batch, train and test size);
+- ``test_set(test_batches) -> tuple``: the eval set as host arrays for
+  ``run_rounds``, its first array as long as the test set;
+- ``program_eval(history, rounds) -> list``: what the program's eval gave
+  after each of the first ``rounds`` rounds;
+- ``eval_numbers(prog_eval, ref) -> dict``: the family's compared eval
+  numbers (a cell's limits file names those it checks);
+- ``plant_wrong_answer(task)``: the family's eval-side fault, an answer
+  altered where it is produced.
+
+``bench/work/<family>.py``, the work counted from the shapes, which the
+per-layer readers take as ``ctx.work``: ``train_flops_per_sample(model)``
+(``round_mfu_pct``), and ``leaf_sizes(model)``,
+``paired_fusion_bytes(leaf_size, clients)`` and
+``paired_fusion_flops(leaf_size, clients)``
+(``paired_fusion_roofline_pct``).
 """
 from __future__ import annotations
 

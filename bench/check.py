@@ -7,17 +7,17 @@ step the server applies) and of the change after all the checked rounds
 are compared as the gap between the program's norm and the reference's.
 Leaves whose first update the reference puts under a thousandth of the
 median leaf's move by round-off alone: they are left out by that rule.
+These numbers hold for every family:
 
 - ``update_gap``, ``change_gap``: the worst leaf, its gap taken over the
   larger of the reference's norm of that leaf and of the median leaf;
 - ``update_rms``: the root mean square over the leaves of the first
   update's gap over the reference's norm, steadier from seed to seed
-  than the worst leaf (PERF.md, "How correct is decided");
-- ``eval_moved``: the share of the test set whose predicted class the
-  program's eval engine puts elsewhere than the reference does, at
-  least: half the summed absolute gap of the two confusion matrices over
-  the test set's size, after the first round (later rounds carry the
-  rounding of more local steps, PERF.md).
+  than the worst leaf (PERF.md, "How correct is decided").
+
+The eval numbers are the family's: ``eval_numbers`` of
+``bench/reference/<family>.py`` compares the program's eval with the
+reference's, and its numbers are merged in.
 """
 from __future__ import annotations
 
@@ -45,12 +45,14 @@ def _rel(prog, ref):
     return np.abs(prog - ref) / ref
 
 
-def compare(prog_thetas: list, prog_confusion: list, ref: dict) -> tuple:
+def compare(prog_thetas: list, prog_eval: list, ref: dict,
+            family) -> tuple:
     """prog_thetas: the program's global before the first checked round
-    and after each; prog_confusion: its eval counts after each. ``ref``
-    is what ``bench/reference/<family>.run_rounds`` returns. Returns the
-    compared numbers, how many leaves were left out, and the per-leaf
-    readings they were taken from."""
+    and after each; prog_eval: its eval after each, as the family's
+    ``program_eval`` reads it. ``ref`` is what the family's
+    ``run_rounds`` returns, ``family`` its module. Returns the compared
+    numbers, how many leaves were left out, and the per-leaf readings
+    they were taken from."""
     r_thetas = ref["thetas"]
     shapes = [np.shape(x) for x in jax.tree_util.tree_leaves(prog_thetas[0])]
     ref_shapes = [np.shape(x) for x in jax.tree_util.tree_leaves(r_thetas[0])]
@@ -62,15 +64,12 @@ def compare(prog_thetas: list, prog_confusion: list, ref: dict) -> tuple:
     p_first = _leaf_norms(prog_thetas[1], prog_thetas[0])
     p_change = _leaf_norms(prog_thetas[-1], prog_thetas[0])
     r_change = _leaf_norms(r_thetas[-1], r_thetas[0])
-    p_conf = np.asarray(prog_confusion[0], np.float64)
-    r_conf = ref["confusion"][0]
     out = {
         "update_gap": _norm_gap(p_first, r_first, keep),
         "update_rms": float(np.sqrt(np.mean(
             _rel(p_first, r_first)[keep] ** 2))),
         "change_gap": _norm_gap(p_change, r_change, keep),
-        "eval_moved": float(np.abs(p_conf - r_conf).sum() / 2
-                            / r_conf.sum()),
+        **family.eval_numbers(prog_eval, ref),
     }
     # per leaf: reference and program norms of the first update, then of
     # the change

@@ -10,12 +10,14 @@ each on the chip at a cell's size, and ``bench/tests`` sees each turn
 - ``frozen``: the round returns the global it was given;
 - ``half_batch``: the local loss is the mean over the first half of each
   batch only;
-- ``dropped``: the first client's update is left out of the fusion, one
-  client's answer lost where it is fused;
+- ``dropped``: the round's first client's update is left out of the
+  fusion, one client's answer lost where it is fused (in a round of
+  several cohort tiles, in the first tile only);
 - ``no_exchange``: fusion sees only the clients on the first chip, as if
-  the all-reduce between chips were left out;
-- ``wrong_answer``: the eval engine's predicted class moves to the next
-  class where it is produced.
+  the all-reduce between chips were left out (in every tile);
+- ``wrong_answer``: the family's eval-side fault, an answer altered where
+  it is produced (``plant_wrong_answer`` of
+  ``bench/reference/<family>.py``).
 """
 from __future__ import annotations
 
@@ -26,7 +28,9 @@ FAULTS = ("control", "frozen", "half_batch", "dropped", "no_exchange",
 CONTROL_PRECISION = "high"
 
 
-def plant_task(fault: str | None, task) -> None:
+def plant_task(fault: str | None, task, family) -> None:
+    """Plant a fault of the task's functions; ``family`` is the cell's
+    ``bench/reference/<family>.py``."""
     if fault == "half_batch":
         loss_fn = task.loss_fn
 
@@ -35,22 +39,17 @@ def plant_task(fault: str | None, task) -> None:
                                     for k, v in batch.items()})
         task.loss_fn = half
     elif fault == "wrong_answer":
-        predict_fn = task.predict_fn
-
-        def shifted(params, batch):
-            pred, gold, w = predict_fn(params, batch)
-            return (pred + 1) % task.n_classes, gold, w
-        task.predict_fn = shifted
+        family.plant_wrong_answer(task)
 
 
-def weights(fault: str | None, w, chips: int):
-    """The fusion weights the faulty round uses instead of ``w``."""
-    if fault not in ("dropped", "no_exchange"):
-        return w
-    w = np.array(w, np.float64)
-    if fault == "dropped":
+def weights(fault: str | None, w, chips: int, first: bool = True):
+    """The fusion weights the faulty tile uses instead of ``w``; ``first``
+    says whether it is the first tile of its round."""
+    if fault == "dropped" and first:
+        w = np.array(w, np.float64)
         w[0] = 0.0
-    else:
+    elif fault == "no_exchange":
+        w = np.array(w, np.float64)
         w[len(w) // chips:] = 0.0
     return w
 

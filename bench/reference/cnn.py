@@ -24,6 +24,11 @@ SGD on its batches, and the server takes the mean of the clients'
 parameters weighted by each client's shard size (Fed2's paired averaging
 with all groups present is that mean, Eq. 19). Evaluation counts a
 (gold, predicted) confusion matrix over the test set.
+
+The family's hooks (``bench/cells.py`` lists the contract): batches of
+``images`` and ``labels``; the program's eval is its confusion matrix
+after each round, compared as ``eval_moved``; the eval-side fault moves
+each predicted class to the next.
 """
 from __future__ import annotations
 
@@ -227,11 +232,19 @@ def run_rounds(model: dict, seed: int, rounds: list, test: tuple, fetch, *,
     rows of each client's batches in step order, and ``weights`` (C,),
     each client's shard size. ``fetch(rows)`` returns the ``images`` and
     ``labels`` of those rows. Clients run on each of ``devices`` (default:
-    the first local device) in equal blocks of at most ``block`` per
-    device, so that one program serves them all; by default the whole
+    the first local device) in blocks of ``block`` per device, equal ones
+    where there are several devices, so that one program serves them all
+    (on one device, a short last block is padded as a tiled round's last
+    tile is, with its first client again at weight 0); by default the whole
     cohort is one block, vmapped as the program's round vmaps it (a
     smaller block compiles to another program whose rounding differs, and
-    the local steps amplify that).
+    the local steps amplify that). Give ``block`` the engine's width per
+    device where a round runs as several tiles. Then the server's mean is
+    taken as a tiled round takes it: each block's mean, by its own
+    weights, scaled by the block's weight and summed, over the round's
+    weight. That is the same mean; its rounding of the parameters, which
+    in a leaf far larger than its update (a norm's scale of one) reads as
+    a gap in the update's norm, is then the tiled round's.
     test: (images, labels) of the evaluation set.
 
     Returns ``thetas``, the global before the first round and after each
@@ -247,8 +260,9 @@ def run_rounds(model: dict, seed: int, rounds: list, test: tuple, fetch, *,
                                        jax.sharding.PartitionSpec("clients"))
     n = len(rounds[0]["weights"]) if rounds else 1
     block = block or n
-    block = max(d for d in range(1, n + 1)
-                if n % d == 0 and d <= block * len(devices))
+    if len(devices) > 1:      # each block splits evenly over the devices
+        block = max(d for d in range(1, n + 1)
+                    if n % d == 0 and d <= block * len(devices))
     theta = jax.jit(lambda: init(seed, model), out_shardings=whole)()
     local = jax.jit(jax.vmap(_local(model, lr, momentum, precision),
                              in_axes=(None, 0, 0)),
@@ -270,19 +284,32 @@ def run_rounds(model: dict, seed: int, rounds: list, test: tuple, fetch, *,
     confs = []
     for rnd in rounds:
         sels = np.asarray(rnd["sels"])
-        w = np.asarray(rnd["weights"], np.float64)
-        w = (w / w.sum()).astype(np.float32)
-        n = len(w)
-        acc = jax.tree_util.tree_map(jnp.zeros_like, theta)
+        w_all = np.asarray(rnd["weights"], np.float64)
+        n = len(w_all)
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, theta)
+        means = []
         for c0 in range(0, n, block):
-            sl = slice(c0, min(n, c0 + block))
-            got = fetch(sels[sl].ravel())
+            real = np.arange(c0, min(n, c0 + block))
+            pad = block - len(real) if len(devices) == 1 else 0
+            slots = np.concatenate([real, np.full(pad, c0)])
+            got = fetch(sels[slots].ravel())
             batch = [jax.device_put(np.asarray(got[k]).reshape(
-                sels[sl].shape + np.shape(got[k])[1:]), split)
+                sels[slots].shape + np.shape(got[k])[1:]), split)
                 for k in ("images", "labels")]
             stacked = local(theta, *batch)
-            acc = weighted_sum(acc, stacked, jax.device_put(w[sl], split))
-        theta = acc
+            s = float(w_all[real].sum())
+            w = np.concatenate([w_all[real] / s,
+                                np.zeros(pad)]).astype(np.float32)
+            means.append((weighted_sum(zeros, stacked,
+                                       jax.device_put(w, split)), s))
+        if len(means) == 1:       # one block: the round's own mean
+            theta = means[0][0]
+        else:
+            acc = jax.tree_util.tree_map(lambda *ls: sum(
+                leaf * s for leaf, (_, s) in zip(ls, means)),
+                *[m for m, _ in means])
+            total = sum(s for _, s in means)
+            theta = jax.tree_util.tree_map(lambda a: a / total, acc)
         thetas.append(jax.tree_util.tree_map(np.asarray, theta))
         images, labels = test
         conf = np.zeros((n_cls, n_cls), np.float64)
@@ -292,3 +319,45 @@ def run_rounds(model: dict, seed: int, rounds: list, test: tuple, fetch, *,
                 jnp.asarray(labels[t0:t0 + 500])))
         confs.append(conf)
     return {"thetas": thetas, "confusion": confs}
+
+
+# -- the family's hooks for the harness ----------------------------------
+
+def batch_shapes(batch) -> dict:
+    """The traffic file's ``expect`` entries that one batch fixes."""
+    return {"image_shape": list(np.shape(batch["images"])[1:])}
+
+
+def test_set(test_batches) -> tuple:
+    """(images, labels) of the eval set as host arrays."""
+    return tuple(np.asarray(test_batches[0][k]) for k in ("images",
+                                                          "labels"))
+
+
+def program_eval(history, rounds: int) -> list:
+    """The eval engine's confusion matrix after each of the first
+    ``rounds`` rounds; ``run_rounds`` records its own under the same key."""
+    return [np.asarray(c) for c in history["confusion"][:rounds]]
+
+
+def eval_numbers(prog_eval: list, ref: dict) -> dict:
+    """``eval_moved``: the share of the test set whose predicted class the
+    program's eval engine puts elsewhere than the reference does, at
+    least: half the summed absolute gap of the two confusion matrices over
+    the test set's size, after the first round (later rounds carry the
+    rounding of more local steps, PERF.md)."""
+    p_conf = np.asarray(prog_eval[0], np.float64)
+    r_conf = ref["confusion"][0]
+    return {"eval_moved": float(np.abs(p_conf - r_conf).sum() / 2
+                                / r_conf.sum())}
+
+
+def plant_wrong_answer(task) -> None:
+    """The eval engine's predicted class moves to the next class where it
+    is produced."""
+    predict_fn = task.predict_fn
+
+    def shifted(params, batch):
+        pred, gold, w = predict_fn(params, batch)
+        return (pred + 1) % task.n_classes, gold, w
+    task.predict_fn = shifted

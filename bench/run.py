@@ -10,7 +10,12 @@ their files give the launcher flags, joined into one argv with
 parses the argv and builds the run (data, partition, model, loader), and
 ``repro.fl.runtime.run_federated`` runs it with ``log=`` set (the
 launcher's per-round sync on the eval result), on a ``"data"`` mesh over
-the cell's chips when it has more than one.
+the cell's chips when it has more than one. A round runs as one engine
+call (``run_round``) or, where it has more participants than the engine's
+width, as several cohort tiles (``run_tile`` each, then
+``finish_round``); the harness follows both. What differs by model family
+is read through the hooks of ``bench/reference/<family>.py``
+(``bench/cells.py`` lists them).
 
 The configuration's ``matmul_precision`` is set in JAX before anything is
 built. Set-up: a warm-up call of ``WARM_ROUNDS`` rounds compiles every
@@ -106,6 +111,7 @@ class Probe:
         self.window = [None, None]   # perf_counter
         self.window_compiles = [None, None]
         self._pending = None
+        self._first_tile = True
 
     # -- hooks ---------------------------------------------------------
     def fetch(self, sel):
@@ -116,20 +122,48 @@ class Probe:
         return b
 
     def wrap_engine(self, make_round_engine):
+        """Wrap the engine's round entries: a whole round
+        (``run_round``), or a tiled round's tiles (``run_tile``) and its
+        server step (``finish_round``). The faults go in through each
+        tile's fusion weights and the global the round returns; the
+        global a round starts from and the one it returns are recorded."""
         from bench import faults
+
+        def tile_weights(weights):
+            w = faults.weights(self.fault, weights, self.chips,
+                               first=self._first_tile)
+            self._first_tile = False
+            return w
+
+        def round_end(gp, new):
+            new = faults.output(self.fault, gp, new)
+            self._first_tile = True
+            if self.timed and len(self.ends) < SETUP_ROUNDS:
+                self._pending = (gp, new)
+            return new
 
         def make(*a, **k):
             engine = make_round_engine(*a, **k)
             run_round = engine.run_round
+            run_tile = engine.run_tile
+            finish_round = engine.finish_round
 
             def timed_round(state, gp, batches, weights=None, **kw):
-                w = faults.weights(self.fault, weights, self.chips)
-                state, new = run_round(state, gp, batches, weights=w, **kw)
-                new = faults.output(self.fault, gp, new)
-                if self.timed and len(self.ends) < SETUP_ROUNDS:
-                    self._pending = (gp, new)
-                return state, new
+                state, new = run_round(state, gp, batches,
+                                       weights=tile_weights(weights), **kw)
+                return state, round_end(gp, new)
+
+            def timed_tile(client_states, server_state, gp, batches,
+                           weights=None, **kw):
+                return run_tile(client_states, server_state, gp, batches,
+                                weights=tile_weights(weights), **kw)
+
+            def timed_finish(server_state, gp, fused):
+                server_state, new = finish_round(server_state, gp, fused)
+                return server_state, round_end(gp, new)
             engine.run_round = timed_round
+            engine.run_tile = timed_tile
+            engine.finish_round = timed_finish
             return engine
         return make
 
@@ -182,52 +216,67 @@ class Probe:
                 f"{self.ends[-1] - self.window[0]:.3f} s < {self.seconds} s")
             self._close_window(self.ends[-1])
 
+    def window_indices(self) -> list:
+        """The timed call's indices of the rounds that ended inside the
+        window."""
+        t0, t1 = self.window
+        return [r for r, t in enumerate(self.ends) if t0 < t <= t1]
+
     def window_rounds(self) -> list:
         """Wall seconds of each round that ended inside the window."""
-        t0, t1 = self.window
-        ends = [t for t in self.ends if t0 < t <= t1]
-        return [b - a for a, b in zip([t0] + ends[:-1], ends)]
+        ends = [self.ends[r] for r in self.window_indices()]
+        return [b - a for a, b in zip([self.window[0]] + ends[:-1], ends)]
 
 
-def _expect(cell, task, fl, parts, get_batch, test_batches) -> None:
-    """The built run has the shapes the traffic and config files state."""
+def _expect(cell, task, fl, parts, get_batch, test_batches,
+            family) -> None:
+    """The built run has the shapes the traffic and config files state:
+    the generic ones here, the batch's through the family's
+    ``batch_shapes``. A size that the configuration's ``model`` states and
+    the task also carries (a classifier's class count) must agree."""
     e = dict(cell.traffic["expect"],
              train_size=cell.config["train_size"],
              test_size=cell.config["test_size"])
-    test = test_batches[0]["images"]
     got = {"population": fl.population, "cohort": fl.cohort_size,
            "steps": fl.local_epochs * fl.steps_per_epoch,
            "batch": fl.batch_size,
-           "image_shape": list(np.shape(get_batch(np.zeros(1, int))
-                                        ["images"])[1:]),
            "train_size": int(sum(len(p) for p in parts)),
-           "test_size": int(np.shape(test)[0])}
-    bad = {k: (e[k], got[k]) for k in e if e[k] != got[k]}
+           "test_size": int(np.shape(family.test_set(test_batches)[0])[0]),
+           **family.batch_shapes(get_batch(np.zeros(1, int)))}
+    bad = {k: (e[k], got.get(k)) for k in e if e[k] != got.get(k)}
     if cell.traffic["chips"] != cell.chips:
         bad["chips"] = (cell.traffic["chips"], cell.chips)
     sgd = cell.config["local_sgd"]
     if (fl.lr, fl.momentum) != (sgd["lr"], sgd["momentum"]):
         bad["local_sgd"] = (sgd, (fl.lr, fl.momentum))
-    if task.n_classes != cell.config["model"]["n_classes"]:
-        bad["n_classes"] = (cell.config["model"]["n_classes"],
-                            task.n_classes)
+    model = cell.config["model"]
+    for f in dataclasses.fields(task):
+        if f.name in model and getattr(task, f.name) != model[f.name]:
+            bad[f.name] = (model[f.name], getattr(task, f.name))
     if bad:
         raise SystemExit(f"bench: the built run differs from the cell's "
                          f"files (want, got): {bad}")
 
 
-def checked_rounds(sels: list, parts, shape: tuple) -> list:
-    """The set-up rounds as the reference takes them: each client's rows
-    (C, S, B), in slot order, and its fusion weight, the size of its own
-    shard (at least 1), found from the rows it drew. A client with an
-    empty shard draws row 0 every time."""
+def checked_rounds(sels: list, parts, steps: int,
+                   participants: list) -> list:
+    """The set-up rounds as the reference takes them: each participant's
+    rows (P, S, B), in slot order, and its fusion weight, the size of its
+    own shard (at least 1), found from the rows it drew. A client with an
+    empty shard draws row 0 every time.
+
+    The round's loader calls hold ``steps`` calls for each engine slot,
+    tile after tile; of a round's ``participants[r]`` clients only the
+    last tile may be short, and its slots past them are padding (the
+    first participant of the tile again, at weight 0): they are left out."""
     owner = np.full(sum(len(p) for p in parts), -1, np.int64)
     for i, p in enumerate(parts):
         owner[np.asarray(p, np.int64)] = i
     sizes = np.array([len(p) for p in parts])
     rounds = []
-    for calls in sels[:SETUP_ROUNDS]:
-        rows = np.stack(calls).reshape(shape + np.shape(calls[0]))
+    for calls, n in zip(sels[:SETUP_ROUNDS], participants):
+        slots = np.stack(calls).reshape((-1, steps) + np.shape(calls[0]))
+        rows = slots[:n]
         ids = owner[rows[:, 0, 0]]
         w = np.maximum(sizes[ids], 1).astype(np.float64)
         empty = np.all(rows == 0, axis=(1, 2)) & (sizes[owner[0]] > 1)
@@ -253,8 +302,9 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
                       cell.config["matmul_precision"])
     args = train.parse_args(cell.argv + ["--seed", str(seed)])
     task, fl, parts, get_batch, test_batches = train.build_fl_run(args)
-    _expect(cell, task, fl, parts, get_batch, test_batches)
-    faults.plant_task(fault, task)
+    family = cells.family_module(cell, "reference")
+    _expect(cell, task, fl, parts, get_batch, test_batches, family)
+    faults.plant_task(fault, task, family)
     mesh = make_data_mesh(cell.chips) if cell.chips > 1 else None
     devices = jax.devices()[:cell.chips]
 
@@ -292,12 +342,15 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
 
     rounds = probe.window_rounds()
     window_s = probe.window[1] - probe.window[0]
-    per_round = fl.cohort_size * fl.local_epochs * fl.steps_per_epoch \
-        * fl.batch_size
+    steps = fl.local_epochs * fl.steps_per_epoch
+    # each round's real participants, its padded slots left out
+    participants = [len(p) for p in h["participants"]]
+    in_window = [participants[r] for r in probe.window_indices()]
+    samples = sum(in_window) * steps * fl.batch_size
     finite = all(bool(np.all(np.isfinite(np.asarray(x))))
                  for x in jax.tree_util.tree_leaves(h["final_params"]))
     window_compiles = probe.window_compiles[1] - probe.window_compiles[0]
-    values = {"samples_per_s": len(rounds) * per_round / window_s,
+    values = {"samples_per_s": samples / window_s,
               "peak_hbm_gib": peak / 2**30,
               "setup_s": setup_s}
     log(f"window: {len(rounds)} rounds in {window_s:.4f} s, "
@@ -308,22 +361,20 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
     # the reference runs once the window is closed, the peak read and
     # the program's state freed
     prog_thetas = probe.thetas
-    prog_conf = [np.asarray(c) for c in h["confusion"][:SETUP_ROUNDS]]
-    test = tuple(np.asarray(test_batches[0][k]) for k in ("images",
-                                                          "labels"))
+    prog_eval = family.program_eval(h, SETUP_ROUNDS)
+    test = family.test_set(test_batches)
     del h, task, test_batches
     gc.collect()
-    ref_rounds = checked_rounds(
-        probe.sels, parts,
-        (fl.cohort_size, fl.local_epochs * fl.steps_per_epoch))
-    reference = cells.family_module(cell, "reference")
+    ref_rounds = checked_rounds(probe.sels, parts, steps, participants)
 
     def follow(precision):
-        return reference.run_rounds(
+        # clients in blocks of the engine's width, vmapped as its tiles are
+        return family.run_rounds(
             cell.config["model"], seed, ref_rounds, test, get_batch,
             lr=cell.config["local_sgd"]["lr"],
             momentum=cell.config["local_sgd"]["momentum"],
-            devices=devices, precision=precision)
+            devices=devices, block=max(1, fl.cohort_size // cell.chips),
+            precision=precision)
 
     t_ref = time.perf_counter()
     ref = follow(cell.config["matmul_precision"])
@@ -331,10 +382,13 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
     if fault == "control":
         # the reference in the program's place, a precision lower; the
         # program's own run is sound, and its numbers come along
-        sound, _, sound_detail = check.compare(prog_thetas, prog_conf, ref)
+        sound, _, sound_detail = check.compare(prog_thetas, prog_eval, ref,
+                                               family)
         ctrl = follow(faults.CONTROL_PRECISION)
-        prog_thetas, prog_conf = ctrl["thetas"], ctrl["confusion"]
-    numbers, left_out, detail = check.compare(prog_thetas, prog_conf, ref)
+        prog_thetas = ctrl["thetas"]
+        prog_eval = family.program_eval(ctrl, SETUP_ROUNDS)
+    numbers, left_out, detail = check.compare(prog_thetas, prog_eval, ref,
+                                              family)
     if sound is not None:
         detail["program"] = sound
         detail["program_leaves"] = sound_detail["leaves"]
@@ -367,7 +421,11 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
             summary=summary,
             rounds=len(rounds),
             samples_per_s=values["samples_per_s"], chips=cell.chips,
-            cohort=fl.cohort_size, model=cell.config["model"],
+            cohort=fl.cohort_size,
+            participants=sum(in_window) / len(in_window),
+            tiles=sum(-(-p // fl.cohort_size) for p in in_window)
+            / len(in_window),
+            model=cell.config["model"],
             work=cells.family_module(cell, "work"),
             peak=cells.peaks(dev.device_kind, cell.root))
         for m in cell.per_layer:
@@ -378,6 +436,8 @@ def run_cell(cell, seed: int, seconds: float, *, trace: bool = False,
         result["breakdown"] = trace_lib.breakdown(summary)
     result["checks"] = checks
     if keep_detail:
+        detail["window"] = {"rounds": len(rounds), "participants": in_window,
+                            "samples": samples, "seconds": window_s}
         result["detail"] = detail
     return result
 
@@ -387,12 +447,18 @@ class MetricContext:
     """What a per-layer metric reader (``bench/metrics/<name>.py``) gets:
     the reduced trace of the window (``bench/trace.py``), the rounds it
     holds, the traced run's throughput, and the cell's sizes, work
-    counter and device peaks."""
+    counter and device peaks. ``cohort`` is the engine's width, the
+    clients one round program or tile fuses; ``participants`` and
+    ``tiles`` are the real clients and the engine tiles of a round, the
+    mean over the window's rounds (100 and 1 where one tile holds a
+    cohort of 100)."""
     summary: dict
     rounds: int
     samples_per_s: float
     chips: int
     cohort: int
+    participants: float
+    tiles: float
     model: dict
     work: object
     peak: dict

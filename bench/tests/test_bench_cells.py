@@ -14,8 +14,12 @@ from bench.tests import tiny
 
 BENCH = cells.load_benchmark()
 WORKLOADS = [w["name"] for w in BENCH["workloads"]]
-CHECKED = {"update_gap", "update_rms", "change_gap", "eval_moved",
-           "window_compiles", "failed_rounds"}
+# the numbers every family compares; the rest of a limits file names the
+# family's own eval numbers (``eval_numbers`` of its reference module)
+GENERIC = {"update_gap", "update_rms", "change_gap", "window_compiles",
+           "failed_rounds"}
+HOOKS = ("run_rounds", "batch_shapes", "test_set", "program_eval",
+         "eval_numbers", "plant_wrong_answer")
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
@@ -33,10 +37,12 @@ def test_cell_resolves_to_an_argv_the_launcher_accepts(workload):
     assert cell.config["matmul_precision"] in ("highest", "high",
                                                "default")
     assert cell.traffic["chips"] == cell.chips
-    assert {"eval_moved", "window_compiles", "failed_rounds"} \
-        <= set(cell.limits) <= CHECKED
+    assert {"window_compiles", "failed_rounds"} <= set(cell.limits)
     assert set(cell.limits) & {"update_gap", "update_rms", "change_gap"}
-    assert cells.family_module(cell, "reference").run_rounds
+    assert set(cell.limits) - GENERIC, "no eval number is compared"
+    reference = cells.family_module(cell, "reference")
+    for hook in HOOKS:
+        assert callable(getattr(reference, hook)), hook
     assert cells.family_module(cell, "work").train_flops_per_sample
     names = {m["name"] for m in cell.end_to_end}
     assert {"setup_s", "samples_per_s", "peak_hbm_gib"} <= names
@@ -64,6 +70,9 @@ def test_a_later_cell_is_new_files_and_entries(tmp_path):
     cell = cells.resolve("tiny_vgg.silo", root=root)
     assert cell.config["name"] == "tiny_vgg"
     assert cell.traffic["expect"]["population"] == 4
+    assert cells.resolve("tiny_vgg.tiled", root=root).traffic["expect"][
+        "cohort"] == 2
+    assert cells.resolve("tiny_acc.silo", root=root).family == "cnn_acc"
     # the cells already there resolve unchanged beside it
     for w in WORKLOADS:
         assert cells.resolve(w, root=root).argv == cells.resolve(w).argv

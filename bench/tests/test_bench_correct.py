@@ -50,10 +50,12 @@ def test_control_and_faults_are_not_correct(cell, fault, caught_by):
 
 def test_compare_on_known_trees():
     """Per-leaf norm gaps by hand; a leaf the reference leaves still is
-    left out; ``eval_moved`` reads the first round only."""
+    left out; the CNN family's ``eval_moved`` reads the first round only
+    and is merged in."""
     import numpy as np
 
     from bench import check
+    from bench.reference import cnn
 
     def tree(a, b, still):
         return {"a": np.full(4, a), "b": np.full(4, b),
@@ -62,13 +64,15 @@ def test_compare_on_known_trees():
            "confusion": [np.eye(2) * 50, np.eye(2) * 50]}
     prog = [tree(0, 0, 0), tree(1.1, 2, 5e-9), tree(2, 4, 0)]
     conf = [np.array([[45.0, 5.0], [0.0, 50.0]]), np.zeros((2, 2))]
-    numbers, left_out, _ = check.compare(prog, conf, ref)
+    numbers, left_out, _ = check.compare(prog, conf, ref, cnn)
     assert left_out == 1
     # leaf a: |2.2 - 2| / max(2, median 3) ; leaf b matches
     assert numbers["update_gap"] == pytest.approx(0.2 / 3)
     assert numbers["update_rms"] == pytest.approx(np.sqrt(0.1 ** 2 / 2))
     assert numbers["change_gap"] == 0.0
-    assert numbers["eval_moved"] == pytest.approx(5 / 100)
+    assert cnn.eval_numbers(conf, ref) == {
+        "eval_moved": pytest.approx(5 / 100)}
+    assert numbers["eval_moved"] == cnn.eval_numbers(conf, ref)["eval_moved"]
 
 
 def test_exchange_left_out_is_not_correct(tmp_path):

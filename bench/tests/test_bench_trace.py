@@ -58,7 +58,8 @@ def _ctx(summary, rounds=1, samples_per_s=1000.0):
     model = cells.resolve("vgg9_fed2.xdev").config["model"]
     return run.MetricContext(
         summary=summary, rounds=rounds, samples_per_s=samples_per_s,
-        chips=1, cohort=10, model=model, work=work,
+        chips=1, cohort=10, participants=10.0, tiles=1.0, model=model,
+        work=work,
         peak=cells.peaks("TPU v5 lite"))
 
 
