@@ -318,8 +318,10 @@ def lm_group_axes(params: PyTree, cfg) -> PyTree:
     axes = {k: shared(v) for k, v in params.items()
             if k not in ("gblocks", "unembed")}
     if cfg.family == "moe" and cfg.moe is not None:
-        # experts are the structure groups: pair expert weights by signature
-        e = cfg.moe.n_experts
+        # experts are the structure groups: pair expert weights by
+        # signature, the experts this chip holds (every client routes
+        # through the same global router, so index i pairs with index i)
+        e = cfg.moe.n_held
 
         def mark_moe(path, leaf):
             names = [str(p) for p in path]

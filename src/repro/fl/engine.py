@@ -339,6 +339,11 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
             "fold server-side work into host_fuse instead")
     opt = meth.local_opt(cfg)
     n = cfg.cohort_size
+    if not task.batched_clients and n > 1:
+        raise ValueError(
+            f"cohort_size {n}: this task's model has no batched form on the "
+            "device (FLTask.batched_clients), so the engine runs one client "
+            "per tile; set cohort_size 1 (the round then runs as tiles)")
     use_kernel = resolve_use_kernel(use_kernel, mesh)
     ga = None
     if meth.uses_groups and task.group_axes_fn is not None:
@@ -401,6 +406,15 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
         return {"server": init_server_state(global_params),
                 "clients": init_client_states(global_params, n)}
 
+    def over_cohort(fn, *args):
+        """``fn`` over the cohort axis of ``args``: vmapped, or, where the
+        task's model has no batched form (``FLTask.batched_clients``), on
+        the cohort's one client, unbatched."""
+        if task.batched_clients:
+            return jax.vmap(fn)(*args)
+        one = fn(*jax.tree_util.tree_map(lambda l: l[0], args))
+        return jax.tree_util.tree_map(lambda l: l[None], one)
+
     def _to_compute(t):
         # bf16 local phase (§15): downcast every float leaf, keep ints
         return jax.tree_util.tree_map(
@@ -446,13 +460,13 @@ def make_round_engine(task, cfg, params_like: PyTree, *, mesh=None,
                                                  server_state, ctx_r)
                     return attack.poison_update(p2, global_params, m, k), cs2
 
-                stacked, new_clients = jax.vmap(one, in_axes=(0, 0, 0, 0, 0))(
-                    stacked, batches, clients_state, row, keys)
+                stacked, new_clients = over_cohort(
+                    one, stacked, batches, clients_state, row, keys)
             else:
-                stacked, new_clients = jax.vmap(
+                stacked, new_clients = over_cohort(
                     lambda p, b, cs: meth.client_update(
                         p, b, gp_local, cs, server_state, ctx_r),
-                    in_axes=(0, 0, 0))(stacked, batches, clients_state)
+                    stacked, batches, clients_state)
             if cdtype is not None:
                 stacked = jax.tree_util.tree_map(
                     lambda l, g: l.astype(g.dtype), stacked, global_params)

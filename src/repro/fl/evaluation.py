@@ -25,7 +25,9 @@ The engine computes example-weighted counts, not per-batch means:
     accuracies fall out of the rows (``per_class_accuracy``,
     ``group_accuracy`` — group g via ``GroupSpec.logit_signature``).
   - ``n_classes=None`` (LM tasks, where classes = vocab): weighted
-    (correct, total) sums only — no vocab^2 confusion is materialized.
+    (correct, total) sums only — no vocab^2 confusion is materialized —
+    and, where ``predict_fn`` also returns each position's negative
+    log-likelihood, its weighted sum as a third entry (``mean_loss``).
 
 Everything stays device-resident until the caller materializes it — one
 host sync per eval at most, none inside the FL round loop
@@ -143,20 +145,25 @@ def make_eval_engine(predict_fn: Callable, n_classes: int | None = None, *,
                      mesh=None) -> EvalEngine:
     """Build the engine for one task.
 
-    predict_fn(params, batch) -> (pred, gold, weight): per-position
+    predict_fn(params, batch) -> (pred, gold, weight[, nll]): per-position
     predictions, gold labels, and example weights — (B,) for classifiers,
-    (B, L) for LMs (weight = the batch's own mask). The staging pad mask
-    multiplies into ``weight``, broadcasting over trailing axes.
+    (B, L) for LMs (weight = the batch's own mask) — and, optionally in
+    counts mode, each position's negative log-likelihood of its gold
+    label. The staging pad mask multiplies into ``weight``, broadcasting
+    over trailing axes.
     """
 
     def one_tile(params, batch, m):
-        pred, gold, w = predict_fn(params, batch)
+        pred, gold, w, *nll = predict_fn(params, batch)
         w = (w.astype(jnp.float32) *
              m.reshape(m.shape + (1,) * (w.ndim - 1)))
         pred, gold, w = pred.ravel(), gold.ravel(), w.ravel()
         if n_classes is None:
             correct = jnp.sum((pred == gold) * w)
-            return jnp.stack([correct, jnp.sum(w)])
+            sums = [correct, jnp.sum(w)]
+            if nll:
+                sums.append(jnp.sum(nll[0].astype(jnp.float32).ravel() * w))
+            return jnp.stack(sums)
         # confusion as a one-hot contraction (C, B) @ (B, C): XLA lowers
         # this to one small matmul — measurably faster than a (B,)-long
         # scatter-add into the (C, C) matrix
@@ -227,6 +234,15 @@ def accuracy(counts) -> float:
     if c.ndim == 1:              # (correct, total)
         return float(c[0] / max(c[1], 1.0))
     return float(np.trace(c) / max(c.sum(), 1.0))
+
+
+def mean_loss(counts) -> float | None:
+    """The weighted mean negative log-likelihood from a counts-mode
+    result that carries one (``(correct, total, nll)``), else None."""
+    c = np.asarray(counts)
+    if c.ndim != 1 or c.shape[0] < 3:
+        return None
+    return float(c[2] / max(c[1], 1.0))
 
 
 def per_class_accuracy(confusion) -> np.ndarray:

@@ -81,7 +81,9 @@ stats are never formatted; the stats are values the code already holds.
   cohort width, rounded up, or the capacity tiers).
 - ``fl.sample``: the sampler's draw of the round's (or async wave's) ids.
 - ``fl.pack``: one engine tile's inputs (``pad_tile_inputs``: padding,
-  weights, packed batches); stats ``clients``, ``steps``, ``batch``.
+  weights, packed batches); stats ``clients``, ``steps``, ``batch``, and
+  ``tokens`` where the batches carry a ``tokens`` leaf (the tile's
+  token count: slots x steps x batch x sequence).
 - ``fl.load``: one ``get_batch`` call inside ``fl.pack``.
 - ``fl.stack``: the single ``jax.device_put`` of a tile's packed host
   buffers (``_pack_client_batches``; stat ``bytes``, the bytes put), or
@@ -105,7 +107,11 @@ Inside the compiled round (fl/engine.py, fl/async_engine.py) the
 ``jax.named_scope`` scopes ``local`` (broadcast and the vmapped local
 phase), ``codec``, ``fuse`` (robust pre-step and the method's fuse) and
 ``server`` name the ops on the device, and the Pallas kernels are named
-``paired_fusion`` and ``local_step``."""
+``paired_fusion`` and ``local_step``. Inside ``local`` the model's own
+scopes name its layers (models/): ``mla`` (latent attention,
+``attention.mla_apply``), ``moe`` (the expert layer, ``moe.moe_apply``)
+with ``route``, ``experts`` and ``shared`` inside it, ``ffn`` (a dense
+SwiGLU FFN) and ``head`` (the LM head and its loss)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -345,6 +351,10 @@ class FLTask:
     # capacity tiers (fl/capacity.py): width -> TierModel sub-model
     # builder; None = the family has no tier support (lm for now).
     tier_fn: Callable[[float], Any] | None = None
+    # False: the model has ops with no batched form on the device (the
+    # held-expert layer's ragged products on a TPU), so the engine runs
+    # one client per tile, unbatched, and refuses a wider cohort
+    batched_clients: bool = True
 
 
 def _pack_client_batches(parts, get_batch, n_steps, batch_size, rng,
@@ -397,7 +407,8 @@ def pad_tile_inputs(pop: Population, tids, width: int, get_batch, n_steps,
     the presence rows to the first K group columns (a tier that dropped
     the rest). Returns (padded_ids, weights, group_weights, batches)."""
     with jax.profiler.TraceAnnotation("fl.pack", clients=width,
-                                      steps=n_steps, batch=batch_size):
+                                      steps=n_steps,
+                                      batch=batch_size) as span:
         tids = np.asarray(tids, np.int64)
         n_real = len(tids)
         padded = np.concatenate(
@@ -416,6 +427,9 @@ def pad_tile_inputs(pop: Population, tids, width: int, get_batch, n_steps,
         batches = _pack_client_batches([pop.parts[i] for i in padded],
                                        get_batch, n_steps, batch_size, rng,
                                        poison_fns=pois)
+        if span.is_enabled() and isinstance(batches, dict) \
+                and "tokens" in batches:
+            span.set_metadata(tokens=int(np.prod(batches["tokens"].shape)))
         return padded, w, gw, batches
 
 
@@ -533,6 +547,12 @@ def run_sampled_round(engine, pop: Population, method, server_state,
         mal = _malicious_inputs(engine, pop, padded, n_real, cfg,
                                 round_idx)
         cstate = pop.gather(method, padded)
+        if acc is not None:
+            # the tile is packed while the last one runs; it is dispatched
+            # once the running sum holds the last one, whose model-sized
+            # output is then freed before this tile allocates: the device
+            # memory follows one order in every round
+            jax.block_until_ready(acc)
         with jax.profiler.TraceAnnotation("fl.dispatch"):
             new_cstate, fuse_out = engine.run_tile(
                 cstate, server_state, global_params, batches, weights=w,
@@ -544,10 +564,14 @@ def run_sampled_round(engine, pop: Population, method, server_state,
                 lambda a: a[:n_real], fuse_out))
             continue
         s_t = float(w.sum())
-        scaled = jax.tree_util.tree_map(lambda l: l * s_t, fuse_out)
-        acc = scaled if acc is None else jax.tree_util.tree_map(
-            jnp.add, acc, scaled)
-        w_acc += s_t
+        if s_t > 0:     # a tile of no weight adds nothing (its mean is 0/0)
+            scaled = jax.tree_util.tree_map(lambda l: l * s_t, fuse_out)
+            # the running sum is updated in place, and the tile's output
+            # dropped before the next tile runs: a model-sized copy each
+            acc = scaled if acc is None else _add_into(acc, scaled)
+            del scaled
+            w_acc += s_t
+        del fuse_out
     if method.host_fusion:
         stacked = jax.tree_util.tree_map(
             lambda *xs: jnp.concatenate(xs, axis=0), *stacked_tiles)
@@ -555,9 +579,32 @@ def run_sampled_round(engine, pop: Population, method, server_state,
                  else pop.weights[ids])
         with jax.profiler.TraceAnnotation("fl.dispatch"):
             return server_state, engine.host_fuse(stacked, w_all)
-    fused = jax.tree_util.tree_map(lambda l: l / w_acc, acc)
+    if acc is None:
+        raise ValueError(f"every participant of the round ({len(ids)}) "
+                         "has fusion weight 0: the round has no mean")
+    fused = _divide_into(acc, np.float32(w_acc))
+    del acc
     with jax.profiler.TraceAnnotation("fl.dispatch"):
         return engine.finish_round(server_state, global_params, fused)
+
+
+# the tiled round's running sum, owned by the loop: each step writes over
+# the sum's own buffers (the same adds and division as eager ops)
+_add_into = jax.jit(lambda acc, x: jax.tree_util.tree_map(jnp.add, acc, x),
+                    donate_argnums=0)
+_divide_into = jax.jit(
+    lambda acc, w: jax.tree_util.tree_map(lambda l: l / w, acc),
+    donate_argnums=0)
+
+
+def _release(old: PyTree, keep: PyTree) -> None:
+    """Free the device buffers of ``old``'s leaves, except those that are
+    leaves of ``keep`` too."""
+    kept = {id(x) for x in jax.tree_util.tree_leaves(keep)}
+    for x in jax.tree_util.tree_leaves(old):
+        if id(x) not in kept and isinstance(x, jax.Array) \
+                and not x.is_deleted():
+            x.delete()
 
 
 def one_shot_config(cfg: FLConfig) -> FLConfig:
@@ -621,6 +668,13 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
     per-round state scatter). ``wall_total`` is the true end-to-end time
     including the final materialization. The loop writes the host spans
     of ``SPANS`` into a running profiler's trace.
+
+    A round that runs as several cohort tiles frees the device buffers of
+    its input global once the round is logged: a hook or a wrapped
+    engine that keeps a global of such a round has to copy it to the
+    host by then (within ``log``). Without that, a caller that keeps two
+    (the benchmark's probe) leaves a model that fills the chip no room to
+    load its tile program (``dsv2lite_fed2.silo``, PERF.md §6).
 
     ``cfg.tiers`` routes the rounds through the heterogeneous-capacity
     engine (fl/capacity.py, DESIGN.md §11): one compiled tile per tier,
@@ -811,6 +865,7 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
                     tiered, pop, method, server_state, global_params, ids,
                     get_batch, n_steps, cfg, rng, uniform_weights=uniform_w)
             else:
+                round_in = global_params
                 server_state, global_params = run_sampled_round(
                     engine, pop, method, server_state, global_params, ids,
                     get_batch, n_steps, cfg, rng, uniform_weights=uniform_w,
@@ -834,6 +889,12 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
             if log:
                 with jax.profiler.TraceAnnotation("fl.log"):
                     log(f"round {r:3d} acc {acc:.4f}")
+            if tiered is None and len(ids) > engine.cohort_size:
+                # a tiled round's input global is freed once the round is
+                # logged, so that a caller's leftover reference (a wrapped
+                # engine's) does not pin a model-sized copy on the device
+                # through the rounds after it
+                _release(round_in, (global_params, server_state))
     if already_complete:
         # resuming a finished run: nothing to train, but callers index
         # h["acc"][-1] — report one eval of the restored model instead
@@ -845,6 +906,9 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
         history["per_class_acc"] = [evaluation_lib.per_class_accuracy(c)
                                     for c in conf]
     history["acc"] = [_count_acc(c) for c in counts]
+    losses = [evaluation_lib.mean_loss(c) for c in counts]
+    if losses and losses[0] is not None:
+        history["eval_loss"] = losses
     history["wall_total"] = time.time() - t0
     history["final_params"] = global_params
     pop.store.close()      # out-of-core stores drop their scratch shards
@@ -906,10 +970,14 @@ def lm_task(model_cfg) -> FLTask:
             jnp.sum(m), 1)
 
     def predict(params, batch):
-        # per-position preds; confusion stays off (n_classes=None: the
-        # "classes" are the vocab — a vocab^2 count matrix is not useful)
-        pred = jnp.argmax(logits_fn(params, batch), -1)
-        return pred, batch["labels"], batch["mask"]
+        # per-position preds and next-token losses; confusion stays off
+        # (n_classes=None: the "classes" are the vocab — a vocab^2 count
+        # matrix is not useful)
+        logits = logits_fn(params, batch).astype(jnp.float32)
+        gold = jnp.take_along_axis(logits, batch["labels"][..., None],
+                                   axis=-1)[..., 0]
+        nll = jax.nn.logsumexp(logits, axis=-1) - gold
+        return jnp.argmax(logits, -1), batch["labels"], batch["mask"], nll
 
     from repro.models.transformer import init_params
     return FLTask(
@@ -920,4 +988,6 @@ def lm_task(model_cfg) -> FLTask:
         matched_average_fn=None,
         predict_fn=predict,
         n_classes=None,
+        batched_clients=(model_cfg.moe is None
+                         or model_cfg.moe.held is None),
     )

@@ -109,8 +109,8 @@ def _decode_attn_flops(cfg: ModelConfig, b: int, s: int) -> float:
             * cfg.n_layers
     h, hd = cfg.n_heads, cfg.head_dim
     s_eff = min(s, cfg.window) if cfg.window else s
-    if cfg.mla_cfg:
-        m = cfg.mla_cfg
+    if cfg.mla:
+        m = cfg.mla
         per = 2.0 * b * h * s_eff * (m.kv_lora + m.qk_rope_dim) * 2
         return per * cfg.n_layers
     per = 4.0 * b * h * hd * s_eff
@@ -131,8 +131,8 @@ def _cache_bytes(cfg: ModelConfig, b: int, s: int) -> float:
         ssm = cfg.ssm
         return 4.0 * b * ssm.n_heads * ssm.headdim * ssm.d_state \
             * cfg.n_layers
-    if cfg.mla_cfg:
-        m = cfg.mla_cfg
+    if cfg.mla:
+        m = cfg.mla
         return 2.0 * b * s * (m.kv_lora + m.qk_rope_dim) * cfg.n_layers
     s_eff = min(s, cfg.window) if cfg.window else s
     kv = 2.0 * b * s_eff * cfg.n_kv_heads * cfg.head_dim * 2

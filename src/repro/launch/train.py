@@ -4,13 +4,23 @@ Two modes:
   --mode lm    : language-model pretraining on the synthetic token corpus
                  for any assigned arch (reduced or full), on the host mesh
                  or a real TPU mesh.
-  --mode fl    : the paper's federated scenario (CNN + Fed2/fedavg/...).
+  --mode fl    : the paper's federated scenario (CNN + Fed2/fedavg/...),
+                 or federated fine-tuning of a language model (an --arch
+                 whose config is a transformer, e.g. deepseek-v2-lite):
+                 the synthetic 8-domain token corpus, partitioned over
+                 domains, --seq tokens a sequence; --layers and
+                 --chips-per-layer cut it to one chip's share
+                 (configs.common.chip_share).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --mode lm \
       --arch llama3.2-1b --reduced --steps 50 --batch 8 --seq 128
   PYTHONPATH=src python -m repro.launch.train --mode fl \
       --arch vgg9 --method fed2 --rounds 10 --nodes 6 --classes-per-node 5
+  PYTHONPATH=src python -m repro.launch.train --mode fl \
+      --arch deepseek-v2-lite --layers 5 --chips-per-layer 8 --nodes 4 \
+      --cohort-size 1 --dirichlet 0.5 --steps-per-epoch 2 --batch 1 \
+      --seq 4096 --train-size 32                  # LM, one chip's share
   PYTHONPATH=src python -m repro.launch.train --mode fl --nodes 64 \
       --cohort-size 16 --sampler uniform          # partial participation
   PYTHONPATH=src python -m repro.launch.train --mode fl --nodes 6 \
@@ -31,6 +41,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def run_lm(args):
@@ -70,19 +81,22 @@ def run_lm(args):
     return float(loss)
 
 
-def build_fl_run(args):
-    """The fl-mode run that the parsed flags describe, ready for
-    ``run_federated``: (task, FLConfig, parts, get_batch, test_batches)."""
-    import importlib
+def _partition(args, labels, n_classes):
+    from repro.data.synthetic import dirichlet_partition, nxc_partition
+    if args.dirichlet > 0:
+        return dirichlet_partition(labels, args.nodes, args.dirichlet,
+                                   n_classes, seed=args.seed)
+    return nxc_partition(labels, args.nodes, args.classes_per_node,
+                         n_classes, seed=args.seed)
 
-    from repro.data.synthetic import (dirichlet_partition,
-                                      make_image_dataset, nxc_partition)
+
+def _cnn_run(args, mod):
+    """The image run: (task, parts, get_batch, test_batches)."""
+    from repro.data.synthetic import make_image_dataset
     from repro.fl import alignment as alignment_lib
     from repro.fl import methods as methods_lib
-    from repro.fl.runtime import FLConfig, cnn_task
+    from repro.fl.runtime import cnn_task
 
-    mod = importlib.import_module(
-        f"repro.configs.{args.arch.replace('-', '_').replace('.', '_')}")
     # model construction routes through THE alignment rule
     # (fl/alignment.py): "grouped" is each method's own structural
     # declaration (the historical branch), "pan"/"none" build plain
@@ -97,18 +111,73 @@ def build_fl_run(args):
     test = make_image_dataset(args.train_size // 4,
                               n_classes=cfg.n_classes, seed=args.seed + 99,
                               noise=args.noise)
-    if args.dirichlet > 0:
-        parts = dirichlet_partition(ds.labels, args.nodes, args.dirichlet,
-                                    cfg.n_classes, seed=args.seed)
-    else:
-        parts = nxc_partition(ds.labels, args.nodes, args.classes_per_node,
-                              cfg.n_classes, seed=args.seed)
+    parts = _partition(args, ds.labels, cfg.n_classes)
 
     def get_batch(sel):
         return {"images": ds.images[sel], "labels": ds.labels[sel]}
 
     test_batches = [{"images": jnp.asarray(test.images),
                      "labels": jnp.asarray(test.labels)}]
+    return cnn_task(cfg), parts, get_batch, test_batches
+
+
+N_DOMAINS = 8       # the token corpus's domains, the LM partition's labels
+
+
+def _lm_run(args, cfg):
+    """The language-model run: one chip's share of ``cfg`` in float32;
+    ``--train-size`` sequences of ``--seq`` tokens from the synthetic
+    corpus (``make_token_dataset``, ids in the share's vocabulary slice),
+    partitioned over its domains, and ``--train-size // 4`` test
+    sequences of the same corpus. Fed2's structure groups are the held
+    experts (``fusion.lm_group_axes``); every other layer is shared and
+    fused by the weighted mean. Returns (task, parts, get_batch,
+    test_batches)."""
+    from repro.configs.common import chip_share
+    from repro.data.synthetic import make_token_dataset
+    from repro.fl.runtime import lm_task
+
+    if args.alignment != "grouped":
+        raise ValueError(f"--alignment {args.alignment}: a language "
+                         "model's structure groups are its experts; only "
+                         "'grouped' applies")
+    cfg = chip_share(cfg, layers=args.layers, chips=args.chips_per_layer)
+    n_test = args.train_size // 4
+    toks, domains = make_token_dataset(args.train_size + n_test,
+                                       args.seq + 1, cfg.vocab,
+                                       n_domains=N_DOMAINS, seed=args.seed)
+    parts = _partition(args, domains[:args.train_size], N_DOMAINS)
+
+    def rows(t):
+        return {"tokens": t[:, :-1], "labels": t[:, 1:],
+                "mask": np.ones((len(t), args.seq), np.float32)}
+
+    def get_batch(sel):
+        return rows(toks[sel])
+
+    return lm_task(cfg), parts, get_batch, [rows(toks[args.train_size:])]
+
+
+def build_fl_run(args):
+    """The fl-mode run that the parsed flags describe, ready for
+    ``run_federated``: (task, FLConfig, parts, get_batch, test_batches).
+    The configuration's kind picks the family: a transformer
+    (``ModelConfig``) trains as a language model, anything else as the
+    image CNN."""
+    import importlib
+
+    from repro.fl.runtime import FLConfig
+    from repro.models.transformer import ModelConfig
+
+    mod = importlib.import_module(
+        f"repro.configs.{args.arch.replace('-', '_').replace('.', '_')}")
+    lm = isinstance(mod.full(), ModelConfig)
+    if lm:
+        task, parts, get_batch, test_batches = _lm_run(
+            args, (mod.reduced if args.reduced else mod.full)(
+                dtype=jnp.float32))
+    else:
+        task, parts, get_batch, test_batches = _cnn_run(args, mod)
     fl = FLConfig(population=args.nodes, cohort_size=args.cohort_size,
                   sampler=args.sampler, rounds=args.rounds,
                   local_epochs=args.local_epochs,
@@ -124,8 +193,10 @@ def build_fl_run(args):
                   compute_dtype=args.compute_dtype,
                   codec=args.codec or None,
                   local_unroll=args.local_unroll,
-                  alignment=args.alignment)
-    return cnn_task(cfg), fl, parts, get_batch, test_batches
+                  alignment=args.alignment,
+                  # an LM's eval tile is one training batch of sequences
+                  **({"eval_batch": args.batch} if lm else {}))
+    return task, fl, parts, get_batch, test_batches
 
 
 def run_fl(args):
@@ -277,6 +348,15 @@ def parse_args(argv=None):
     ap.add_argument("--steps-per-epoch", type=int, default=8)
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="fl mode, language models: hold the first N "
+                         "layers (the rest lie on further chips as "
+                         "pipeline stages; configs.common.chip_share)")
+    ap.add_argument("--chips-per-layer", type=int, default=1,
+                    help="fl mode, language models: this chip is one of "
+                         "K sharing each layer by expert parallelism, and "
+                         "holds 1/K of each MoE layer's experts and of the "
+                         "vocabulary")
     ap.add_argument("--lr", type=float, default=0.01)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--train-size", type=int, default=4000)

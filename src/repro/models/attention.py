@@ -20,8 +20,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import (apply_rope, dense_apply, dense_init,
-                                 rmsnorm_apply, rmsnorm_init, rope_freqs)
+from repro.models.layers import (YaRN, apply_rope, dense_apply, dense_init,
+                                 rmsnorm_apply, rmsnorm_init, rope_freqs,
+                                 yarn_softmax_mscale)
 
 NEG_INF = -1e30
 
@@ -268,27 +269,52 @@ def gqa_decode(p, x, cache, cfg: AttnConfig, *, pos):
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
+    """DeepSeek-V2's latent attention sizes. ``q_lora=None`` projects
+    queries straight from the hidden state (DeepSeek-V2-Lite); an int
+    routes them through a rank-``q_lora`` latent with its own RMSNorm.
+    ``rope_scaling`` is the YaRN of the rope dims (None: plain rope).
+
+    The rope dims use the half-split rotation of ``apply_rope``; upstream
+    first interleaves them (``view(d/2, 2).transpose``), a fixed
+    permutation of the rope columns of ``wq``/``wkv_a`` that random
+    weights do not see."""
     d_model: int
     n_heads: int
     kv_lora: int = 512
-    q_lora: int = 1536
+    q_lora: int | None = 1536
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 10000.0
+    rope_scaling: YaRN | None = None
 
     @property
     def qk_head_dim(self):
         return self.qk_nope_dim + self.qk_rope_dim
 
+    @property
+    def softmax_scale(self) -> float:
+        return self.qk_head_dim ** -0.5 * yarn_softmax_mscale(
+            self.rope_scaling)
+
+    def inv_freq(self):
+        return rope_freqs(self.qk_rope_dim, self.rope_theta,
+                          yarn=self.rope_scaling)
+
 
 def mla_init(key, cfg: MLAConfig, dtype=jnp.float32):
     ks = jax.random.split(key, 7)
     h = cfg.n_heads
+    if cfg.q_lora is None:
+        q = {"wq": dense_init(ks[1], cfg.d_model, h * cfg.qk_head_dim,
+                              dtype=dtype)}
+    else:
+        q = {"wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora, dtype=dtype),
+             "q_a_norm": rmsnorm_init(cfg.q_lora, dtype),
+             "wq_b": dense_init(ks[1], cfg.q_lora, h * cfg.qk_head_dim,
+                                dtype=dtype)}
     return {
-        "wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora, dtype=dtype),
-        "q_a_norm": rmsnorm_init(cfg.q_lora, dtype),
-        "wq_b": dense_init(ks[1], cfg.q_lora, h * cfg.qk_head_dim, dtype=dtype),
+        **q,
         "wkv_a": dense_init(ks[2], cfg.d_model, cfg.kv_lora + cfg.qk_rope_dim,
                             dtype=dtype),
         "kv_a_norm": rmsnorm_init(cfg.kv_lora, dtype),
@@ -300,12 +326,15 @@ def mla_init(key, cfg: MLAConfig, dtype=jnp.float32):
 
 def _mla_q(p, x, cfg: MLAConfig, positions):
     b, s, _ = x.shape
-    cq = rmsnorm_apply(p["q_a_norm"], dense_apply(p["wq_a"], x))
-    q = dense_apply(p["wq_b"], cq).reshape(b, s, cfg.n_heads, cfg.qk_head_dim)
+    if cfg.q_lora is None:
+        q = dense_apply(p["wq"], x)
+    else:
+        q = dense_apply(p["wq_b"],
+                        rmsnorm_apply(p["q_a_norm"], dense_apply(p["wq_a"], x)))
+    q = q.reshape(b, s, cfg.n_heads, cfg.qk_head_dim)
     q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
-    inv = rope_freqs(cfg.qk_rope_dim, cfg.rope_theta)
     pos_b = jnp.broadcast_to(positions[None, :], (b, s))
-    q_rope = apply_rope(q_rope, pos_b, inv)
+    q_rope = apply_rope(q_rope, pos_b, cfg.inv_freq())
     return q_nope, q_rope
 
 
@@ -314,35 +343,42 @@ def _mla_latent(p, x, cfg: MLAConfig, positions):
     kv = dense_apply(p["wkv_a"], x)
     c_kv = rmsnorm_apply(p["kv_a_norm"], kv[..., :cfg.kv_lora])
     k_rope = kv[..., cfg.kv_lora:].reshape(b, s, 1, cfg.qk_rope_dim)
-    inv = rope_freqs(cfg.qk_rope_dim, cfg.rope_theta)
     pos_b = jnp.broadcast_to(positions[None, :], (b, s))
-    k_rope = apply_rope(k_rope, pos_b, inv)[:, :, 0]
+    k_rope = apply_rope(k_rope, pos_b, cfg.inv_freq())[:, :, 0]
     return c_kv, k_rope  # (B,S,kv_lora), (B,S,rope_dim)
 
 
 def mla_apply(p, x, cfg: MLAConfig, *, positions=None, q_chunk=512,
               kv_chunk=1024):
-    """Prefill/train path: expand latent to per-head K/V, chunked attention."""
-    b, s, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(s)
-    q_nope, q_rope = _mla_q(p, x, cfg, positions)
-    c_kv, k_rope = _mla_latent(p, x, cfg, positions)
-    h = cfg.n_heads
-    k_nope = dense_apply(p["wk_b"], c_kv).reshape(b, s, h, cfg.qk_nope_dim)
-    v = dense_apply(p["wv_b"], c_kv).reshape(b, s, h, cfg.v_head_dim)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate([k_nope,
-                         jnp.broadcast_to(k_rope[:, :, None],
-                                          (b, s, h, cfg.qk_rope_dim))], axis=-1)
-    # pad v to qk_head_dim so the chunked kernel can share shapes
-    vpad = jnp.pad(v, ((0, 0), (0, 0), (0, 0),
-                       (0, cfg.qk_head_dim - cfg.v_head_dim)))
-    o = chunked_attention(q, k, vpad, q_positions=positions,
-                          kv_positions=positions, causal=True,
-                          q_chunk=q_chunk, kv_chunk=kv_chunk)
-    o = o[..., :cfg.v_head_dim].reshape(b, s, h * cfg.v_head_dim)
-    return dense_apply(p["wo"], o)
+    """Prefill/train path: expand latent to per-head K/V, chunked attention.
+    The chunked kernel scales scores by ``qk_head_dim**-0.5``; YaRN's
+    further ``mscale**2`` is folded into the queries."""
+    with jax.named_scope("mla"):
+        b, s, _ = x.shape
+        if positions is None:
+            positions = jnp.arange(s)
+        q_nope, q_rope = _mla_q(p, x, cfg, positions)
+        c_kv, k_rope = _mla_latent(p, x, cfg, positions)
+        h = cfg.n_heads
+        k_nope = dense_apply(p["wk_b"], c_kv).reshape(b, s, h,
+                                                      cfg.qk_nope_dim)
+        v = dense_apply(p["wv_b"], c_kv).reshape(b, s, h, cfg.v_head_dim)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        mscale = yarn_softmax_mscale(cfg.rope_scaling)
+        if mscale != 1.0:
+            q = q * mscale
+        k = jnp.concatenate([k_nope,
+                             jnp.broadcast_to(k_rope[:, :, None],
+                                              (b, s, h, cfg.qk_rope_dim))],
+                            axis=-1)
+        # pad v to qk_head_dim so the chunked kernel can share shapes
+        vpad = jnp.pad(v, ((0, 0), (0, 0), (0, 0),
+                           (0, cfg.qk_head_dim - cfg.v_head_dim)))
+        o = chunked_attention(q, k, vpad, q_positions=positions,
+                              kv_positions=positions, causal=True,
+                              q_chunk=q_chunk, kv_chunk=kv_chunk)
+        o = o[..., :cfg.v_head_dim].reshape(b, s, h * cfg.v_head_dim)
+        return dense_apply(p["wo"], o)
 
 
 def mla_cache_init(cfg: MLAConfig, batch: int, max_len: int, dtype):
@@ -370,8 +406,7 @@ def mla_decode(p, x, cache, cfg: MLAConfig, *, pos):
     q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0], wk_b)
     s_lat = jnp.einsum("bhl,bsl->bhs", q_lat, ck)
     s_rope = jnp.einsum("bhd,bsd->bhs", q_rope[:, 0], cr)
-    scale = 1.0 / np.sqrt(cfg.qk_head_dim)
-    s = (s_lat + s_rope).astype(jnp.float32) * scale
+    s = (s_lat + s_rope).astype(jnp.float32) * cfg.softmax_scale
     valid = (spos >= 0) & (spos <= pos)
     s = jnp.where(valid[None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)

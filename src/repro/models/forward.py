@@ -65,7 +65,7 @@ def forward(params, cfg: ModelConfig, tokens, *, embeds=None, positions=None):
             dcfg = dataclasses.replace(cfg, d_ff=cfg.moe_dense_ff)
             apply_pre = functools.partial(_apply_pre_block, cfg=dcfg,
                                           positions=positions,
-                                          mla=cfg.mla_cfg is not None)
+                                          mla=cfg.mla is not None)
             x, a = _scan_blocks(params["pre_blocks"], x, apply_pre,
                                 cfg.remat_blocks)
             aux_total += a
@@ -90,7 +90,7 @@ def _apply_pre_block(p, x, *, cfg, positions, mla):
     aux = jnp.zeros((), jnp.float32)
     h = _norm_apply(cfg, p["ln1"], x)
     if mla:
-        a = attn.mla_apply(p["attn"], h, cfg.mla_cfg, positions=positions,
+        a = attn.mla_apply(p["attn"], h, cfg.mla, positions=positions,
                            q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk)
     else:
         a = attn.gqa_apply(p["attn"], h, cfg.attn_cfg, positions=positions,
@@ -197,15 +197,16 @@ def _forward_encdec(params, cfg: ModelConfig, tokens, frames):
 # ---------------------------------------------------------------------------
 
 
-def lm_loss(params, cfg: ModelConfig, batch, *, aux_weight: float = 0.01):
+def lm_loss(params, cfg: ModelConfig, batch):
     """batch: {"tokens": (B,S), "labels": (B,S), "mask": (B,S),
-    optional "embeds": frontend stub output}."""
+    optional "embeds": frontend stub output}. The MoE load-balance term
+    is weighed by the config's ``aux_loss_weight``."""
     h, aux = forward(params, cfg, batch["tokens"],
                      embeds=batch.get("embeds"))
     if cfg.family == "vlm":  # loss only on text positions
         h = h[:, cfg.n_patches:]
     loss = chunked_ce_loss(params, h, batch["labels"], batch["mask"], cfg)
-    return loss + aux_weight * aux
+    return loss + cfg.aux_loss_weight * aux
 
 
 # ---------------------------------------------------------------------------
@@ -217,7 +218,7 @@ def _block_cache_init(cfg: ModelConfig, kind: str, batch: int, max_len: int):
     if kind == "ssm":
         return ssm_lib.mamba2_cache_init(cfg.ssm, batch, cfg.dtype)
     if kind == "mla_moe" or kind == "mla_dense":
-        return attn.mla_cache_init(cfg.mla_cfg, batch, max_len, cfg.dtype)
+        return attn.mla_cache_init(cfg.mla, batch, max_len, cfg.dtype)
     return attn.gqa_cache_init(cfg.attn_cfg, batch, max_len, cfg.dtype)
 
 
@@ -258,7 +259,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
     kind = tfm._default_kind(cfg)
     cache = {}
     if "moe" == cfg.family and cfg.moe_first_dense:
-        pk = "mla_dense" if cfg.mla_cfg else "attn_ffn"
+        pk = "mla_dense" if cfg.mla else "attn_ffn"
         cache["pre_blocks"] = _stacked_cache(
             cfg.moe_first_dense, _block_cache_init(cfg, pk, batch, max_len))
     cache["blocks"] = _stacked_cache(
@@ -318,9 +319,9 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
 
 
 def _pre_block_decode(p, x, c, dcfg, pos):
-    if dcfg.mla_cfg:
+    if dcfg.mla:
         h = _norm_apply(dcfg, p["ln1"], x)
-        a, c = attn.mla_decode(p["attn"], h, c, dcfg.mla_cfg, pos=pos)
+        a, c = attn.mla_decode(p["attn"], h, c, dcfg.mla, pos=pos)
         x = x + a
         x = x + tfm.ffn_apply(p["ffn"], _norm_apply(dcfg, p["ln2"], x), dcfg)
         return x, c

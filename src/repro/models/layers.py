@@ -6,6 +6,9 @@ gradients cannot flow across groups — Fed2's feature isolation (Eq. 13-14).
 """
 from __future__ import annotations
 
+import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -175,10 +178,62 @@ def conv1d_depthwise_apply(p, x, *, causal: bool = True):
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2's
+    ``rope_scaling`` states it: frequencies past the correction range are
+    divided by ``factor``, those inside it are kept, with a linear ramp
+    between; attention logits are scaled by ``softmax_mscale``."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+
+    def __post_init__(self):
+        # the rotation's cos/sin carry m(factor, mscale) / m(factor,
+        # mscale_all_dim), which is 1 where the two agree, as in both
+        # DeepSeek-V2 configs; rope_freqs applies no other factor
+        if self.mscale != self.mscale_all_dim:
+            raise ValueError("YaRN with mscale != mscale_all_dim scales "
+                             "cos/sin, which apply_rope does not")
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_softmax_mscale(yarn: YaRN | None) -> float:
+    """The factor on the ``d**-0.5`` softmax scale: ``m(factor,
+    mscale_all_dim)**2`` (1 without YaRN)."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def _yarn_inv_freq(rd: int, theta: float, yarn: YaRN) -> np.ndarray:
+    extra = 1.0 / (theta ** (np.arange(0, rd, 2, dtype=np.float32) / rd))
+    inter = extra / yarn.factor
+
+    def dim(rotations):
+        return (rd * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), rd - 1)
+    ramp = np.clip((np.arange(rd // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
 def rope_freqs(head_dim: int, theta: float = 10000.0,
-               rotary_dim: int | None = None):
+               rotary_dim: int | None = None, yarn: YaRN | None = None):
     rd = rotary_dim if rotary_dim is not None else head_dim
     assert rd % 2 == 0
+    if yarn is not None:
+        return jnp.asarray(_yarn_inv_freq(rd, theta, yarn))
     inv = 1.0 / (theta ** (np.arange(0, rd, 2, dtype=np.float32) / rd))
     return jnp.asarray(inv)  # (rd/2,)
 

@@ -59,8 +59,12 @@ class ModelConfig:
     window: int | None = None       # sliding-window attention
     use_rope: bool = True           # whisper decoder uses learned abs pos
     max_position: int = 1 << 19
+    # latent attention (deepseek-v2): its sizes and rope scaling; None
+    # for every other attention
+    mla: attn.MLAConfig | None = None
     # moe
     moe: moe_lib.MoEConfig | None = None
+    aux_loss_weight: float = 0.01   # load-balance term in lm_loss
     moe_first_dense: int = 0        # deepseek-v2: first layer dense FFN
     moe_dense_ff: int = 0
     # ssm / hybrid
@@ -96,13 +100,6 @@ class ModelConfig:
             rope_theta=self.rope_theta,
             rotary_pct=self.rotary_pct if self.use_rope else 0.0,
             qkv_bias=self.qkv_bias, qk_norm=self.qk_norm, window=self.window)
-
-    @property
-    def mla_cfg(self) -> attn.MLAConfig | None:
-        if self.arch_id.startswith("deepseek"):
-            return attn.MLAConfig(d_model=self.d_model, n_heads=self.n_heads,
-                                  rope_theta=self.rope_theta)
-        return None
 
     @property
     def n_dense_blocks(self) -> int:
@@ -156,9 +153,10 @@ def ffn_init(key, cfg: ModelConfig, d_ff=None, dtype=None):
 
 
 def ffn_apply(p, x, cfg: ModelConfig):
-    return dense_apply(p["w_down"],
-                       _act(cfg, dense_apply(p["w_gate"], x),
-                            dense_apply(p["w_up"], x)))
+    with jax.named_scope("ffn"):
+        return dense_apply(p["w_down"],
+                           _act(cfg, dense_apply(p["w_gate"], x),
+                                dense_apply(p["w_up"], x)))
 
 
 def gffn_init(key, cfg: ModelConfig, dtype=None):
@@ -195,7 +193,7 @@ def block_init(key, cfg: ModelConfig, *, grouped: bool = False,
         p["mixer"] = ssm_lib.mamba2_init(ks[0], cfg.ssm, cfg.dtype)
         return p
     if kind == "mla_moe":
-        p["attn"] = attn.mla_init(ks[0], cfg.mla_cfg, cfg.dtype)
+        p["attn"] = attn.mla_init(ks[0], cfg.mla, cfg.dtype)
     else:
         p["attn"] = attn.gqa_init(ks[0], cfg.attn_cfg, cfg.dtype)
     p["ln2"] = _norm_init(cfg)
@@ -212,7 +210,7 @@ def _default_kind(cfg: ModelConfig) -> str:
     if cfg.family == "ssm":
         return "ssm"
     if cfg.family == "moe":
-        return "mla_moe" if cfg.mla_cfg else "moe"
+        return "mla_moe" if cfg.mla else "moe"
     return "attn_ffn"
 
 
@@ -225,7 +223,7 @@ def block_apply(p, x, cfg: ModelConfig, *, grouped: bool = False,
                                         cfg.ssm), aux
     h = _norm_apply(cfg, p["ln1"], x)
     if kind == "mla_moe":
-        a = attn.mla_apply(p["attn"], h, cfg.mla_cfg, positions=positions,
+        a = attn.mla_apply(p["attn"], h, cfg.mla, positions=positions,
                            q_chunk=cfg.attn_q_chunk, kv_chunk=cfg.attn_kv_chunk)
     else:
         a = attn.gqa_apply(p["attn"], h, cfg.attn_cfg, positions=positions,
@@ -251,7 +249,7 @@ def block_decode(p, x, cache, cfg: ModelConfig, *, pos, kind=None,
         return x + y, cache
     h = _norm_apply(cfg, p["ln1"], x)
     if kind == "mla_moe":
-        a, cache = attn.mla_decode(p["attn"], h, cache, cfg.mla_cfg, pos=pos)
+        a, cache = attn.mla_decode(p["attn"], h, cache, cfg.mla, pos=pos)
     else:
         a, cache = attn.gqa_decode(p["attn"], h, cache, cfg.attn_cfg, pos=pos)
     x = x + a
@@ -304,11 +302,13 @@ def chunked_ce_loss(params, h, labels, mask, cfg: ModelConfig):
 
     @jax.checkpoint
     def chunk_loss(hc, lc, mc):
-        logits = unembed_apply(params["unembed"] if not cfg.tie_embeddings
-                               else None, hc, cfg, table).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        return jnp.sum((lse - gold) * mc), jnp.sum(mc)
+        with jax.named_scope("head"):
+            logits = unembed_apply(params["unembed"] if not cfg.tie_embeddings
+                                   else None, hc, cfg, table
+                                   ).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+            return jnp.sum((lse - gold) * mc), jnp.sum(mc)
 
     def body(acc, inp):
         l, n = chunk_loss(*inp)
@@ -359,9 +359,9 @@ def init_params(key, cfg: ModelConfig):
             dcfg = dataclasses.replace(cfg, d_ff=cfg.moe_dense_ff)
             params["pre_blocks"] = stack_init(
                 functools.partial(block_init, cfg=dcfg,
-                                  kind="attn_ffn" if not cfg.mla_cfg else None),
+                                  kind="attn_ffn" if not cfg.mla else None),
                 ks[5], cfg.moe_first_dense)
-            if cfg.mla_cfg:  # deepseek dense layer still uses MLA attention
+            if cfg.mla:  # deepseek dense layer still uses MLA attention
                 params["pre_blocks"] = stack_init(
                     functools.partial(_mla_dense_block_init, cfg=dcfg),
                     ks[5], cfg.moe_first_dense)
@@ -431,7 +431,7 @@ def _encdec_dec_block_init(key, cfg, grouped=False):
 def _mla_dense_block_init(key, cfg):
     ks = jax.random.split(key, 2)
     return {"ln1": _norm_init(cfg),
-            "attn": attn.mla_init(ks[0], cfg.mla_cfg, cfg.dtype),
+            "attn": attn.mla_init(ks[0], cfg.mla, cfg.dtype),
             "ln2": _norm_init(cfg), "ffn": ffn_init(ks[1], cfg)}
 
 

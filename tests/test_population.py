@@ -319,6 +319,63 @@ def test_cohort_tiling_matches_single_cohort(method):
                                    atol=1e-5)
 
 
+def _tiled_round(weights):
+    """One fedavg round of 4 participants in tiles of 2, the population's
+    fusion weights set to ``weights``."""
+    parts = nxc_partition(_DS.labels, 4, 2, 4, seed=1)
+    fl = _fl("fedavg", population=4, cohort_size=2)
+    task = cnn_task(_plain_cfg())
+    pop = Population.from_parts(parts)
+    pop.weights = np.asarray(weights, np.float64)
+    gp = task.init_fn(jax.random.PRNGKey(0))
+    engine = make_round_engine(task, fl, gp, use_kernel=False)
+    pop.clients = engine.init_population_state(gp, pop.size)
+    return run_sampled_round(engine, pop, methods_lib.get("fedavg"),
+                             engine.init_server_state(gp), gp,
+                             np.arange(4), _get_batch, 2, fl,
+                             np.random.default_rng(0))[1]
+
+
+def test_cohort_tiling_tile_of_no_weight_adds_nothing():
+    """A tile whose participants all weigh 0 leaves the round's mean to
+    the other tiles (its own mean would be 0/0); a round of no weight at
+    all has no mean."""
+    got = _tiled_round([3.0, 1.0, 0.0, 0.0])
+    assert all(bool(jnp.all(jnp.isfinite(x)))
+               for x in jax.tree_util.tree_leaves(got))
+    with pytest.raises(ValueError, match="no mean"):
+        _tiled_round([0.0, 0.0, 0.0, 0.0])
+
+
+def test_tiled_round_input_global_is_freed_once_logged(monkeypatch):
+    """A caller that keeps the globals a tiled round returns (a wrapped
+    engine) pins no device copy: each round's input global is deleted
+    once the round is logged; the final global is kept."""
+    from repro.fl import runtime
+    kept = []
+    make = runtime.make_round_engine
+
+    def keeping(*a, **k):
+        engine = make(*a, **k)
+        finish = engine.finish_round
+
+        def finish_round(server_state, gp, fused):
+            out = finish(server_state, gp, fused)
+            kept.append(out[1])
+            return out
+        engine.finish_round = finish_round
+        return engine
+    monkeypatch.setattr(runtime, "make_round_engine", keeping)
+    parts = nxc_partition(_DS.labels, 4, 2, 4, seed=1)
+    h = run_federated(cnn_task(_plain_cfg()),
+                      _fl("fedavg", population=4, cohort_size=2, rounds=3),
+                      parts, _get_batch, _TEST_BATCHES, log=lambda m: None)
+    leaves = [jax.tree_util.tree_leaves(g) for g in kept]
+    assert all(x.is_deleted() for g in leaves[:-1] for x in g)
+    assert not any(x.is_deleted() for x in leaves[-1])
+    assert leaves[-1][0] is jax.tree_util.tree_leaves(h["final_params"])[0]
+
+
 def test_cohort_tiling_host_fusion_concatenates_participants():
     """fedma under tiling: tiles hand their stacked params to the host,
     matching runs ONCE over all participants — same result as one tile."""
