@@ -78,7 +78,12 @@ stats are never formatted; the stats are values the code already holds.
 - ``fl.round``: one iteration of the sync round loop, from sampling
   through ``log`` (a ``StepTraceAnnotation``, ``step_num`` = the round);
   stats ``participants``, ``tiles`` (engine tiles: participants over the
-  cohort width, rounded up, or the capacity tiers).
+  cohort width, rounded up, or the capacity tiers), and where the task
+  states them (``FLTask.attn_blocks``: a language model of a known
+  sequence length) ``attn_blocks`` and ``attn_blocks_live``, the
+  (query chunk, key chunk) blocks of one attention layer over one
+  sequence and those of them it computes
+  (``models.attention.attention_blocks``).
 - ``fl.sample``: the sampler's draw of the round's (or async wave's) ids.
 - ``fl.pack``: one engine tile's inputs (``pad_tile_inputs``: padding,
   weights, packed batches); stats ``clients``, ``steps``, ``batch``, and
@@ -355,6 +360,10 @@ class FLTask:
     # held-expert layer's ragged products on a TPU), so the engine runs
     # one client per tile, unbatched, and refuses a wider cohort
     batched_clients: bool = True
+    # (live, total) attention blocks one layer computes over one sequence
+    # (models.attention.attention_blocks); None: no attention, or the
+    # sequence length is not known
+    attn_blocks: tuple[int, int] | None = None
 
 
 def _pack_client_batches(parts, get_batch, n_steps, batch_size, rng,
@@ -855,10 +864,13 @@ def run_federated(task: FLTask, cfg: FLConfig, parts, get_batch,
                 ids = sampler.sample(r, cfg.population, cfg.cohort_size,
                                      rng, weights=pop.weights)
             if round_span.is_enabled():
-                round_span.set_metadata(
-                    participants=len(ids),
-                    tiles=(len(tiered.tiles) if tiered is not None
-                           else -(-len(ids) // cfg.cohort_size)))
+                stats = {"participants": len(ids),
+                         "tiles": (len(tiered.tiles) if tiered is not None
+                                   else -(-len(ids) // cfg.cohort_size))}
+                if task.attn_blocks is not None:
+                    (stats["attn_blocks_live"],
+                     stats["attn_blocks"]) = task.attn_blocks
+                round_span.set_metadata(**stats)
             if tiered is not None:
                 from repro.fl.capacity import run_tiered_round
                 server_state, global_params = run_tiered_round(
@@ -952,7 +964,10 @@ def cnn_task(model_cfg) -> FLTask:
     )
 
 
-def lm_task(model_cfg) -> FLTask:
+def lm_task(model_cfg, seq: int | None = None) -> FLTask:
+    """The language-model task; ``seq``, the training sequences' length,
+    lets it state the attention blocks a layer computes
+    (``FLTask.attn_blocks``)."""
     from repro.models.forward import lm_loss
 
     def logits_fn(params, batch):
@@ -990,4 +1005,18 @@ def lm_task(model_cfg) -> FLTask:
         n_classes=None,
         batched_clients=(model_cfg.moe is None
                          or model_cfg.moe.held is None),
+        attn_blocks=None if seq is None else _lm_attn_blocks(model_cfg, seq),
     )
+
+
+def _lm_attn_blocks(model_cfg, seq: int) -> tuple[int, int] | None:
+    """(live, total) blocks of one causal self-attention layer over ``seq``
+    tokens, at the canonical positions ``forward`` gives it (MLA has no
+    window); None for a model with no attention (ssm)."""
+    from repro.models.attention import attention_blocks
+    if model_cfg.family == "ssm":
+        return None
+    return attention_blocks(
+        seq, seq, causal=True,
+        window=None if model_cfg.mla is not None else model_cfg.window,
+        q_chunk=model_cfg.attn_q_chunk, kv_chunk=model_cfg.attn_kv_chunk)

@@ -155,7 +155,8 @@ def _lm_run(args, cfg):
     def get_batch(sel):
         return rows(toks[sel])
 
-    return lm_task(cfg), parts, get_batch, [rows(toks[args.train_size:])]
+    return (lm_task(cfg, seq=args.seq), parts, get_batch,
+            [rows(toks[args.train_size:])])
 
 
 def build_fl_run(args):

@@ -15,6 +15,7 @@ Decode paths consume a KV cache:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -48,13 +49,51 @@ def _attend_block(q, k, v, bias):
     return o.reshape(b, hq, tq, d), m.reshape(b, hq, tq, 1), l.reshape(b, hq, tq, 1)
 
 
-def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
-                      window: int | None = None, q_chunk: int = 512,
-                      kv_chunk: int = 1024, kv_valid_len=None):
+def attention_schedule(sq: int, skv: int, *, causal: bool,
+                       window: int | None = None, q_chunk: int = 512,
+                       kv_chunk: int = 1024) -> tuple[tuple[int, int], ...]:
+    """The key chunks each query chunk of ``chunked_attention`` scans when
+    the positions are the canonical ``0..S-1``: one ``(lo, hi)`` range
+    (``hi`` exclusive) per query chunk. A chunk outside it holds no
+    position the chunk's queries can see: past the last query under
+    ``causal``, before the first query's window under ``window``."""
+    q_chunk, kv_chunk = min(q_chunk, sq), min(kv_chunk, skv)
+    nq, nk = -(-sq // q_chunk), -(-skv // kv_chunk)
+    spans = []
+    for i in range(nq):
+        first, last = i * q_chunk, min((i + 1) * q_chunk, sq) - 1
+        hi = min(last // kv_chunk + 1, nk) if causal else nk
+        lo = max(first - window + 1, 0) // kv_chunk if window else 0
+        spans.append((lo, hi))
+    return tuple(spans)
+
+
+def attention_blocks(sq: int, skv: int, *, causal: bool,
+                     window: int | None = None, q_chunk: int = 512,
+                     kv_chunk: int = 1024) -> tuple[int, int]:
+    """(live, total): the (query chunk, key chunk) blocks that
+    ``chunked_attention`` computes at canonical positions, and all of
+    them."""
+    spans = attention_schedule(sq, skv, causal=causal, window=window,
+                               q_chunk=q_chunk, kv_chunk=kv_chunk)
+    nk = -(-skv // min(kv_chunk, skv))
+    return sum(hi - lo for lo, hi in spans), len(spans) * nk
+
+
+def chunked_attention(q, k, v, *, q_positions=None, kv_positions=None,
+                      causal: bool, window: int | None = None,
+                      q_chunk: int = 512, kv_chunk: int = 1024,
+                      kv_valid_len=None):
     """Online-softmax attention.
 
     q: (B, S_q, Hq, D); k, v: (B, S_kv, Hkv, D); positions: (S_q,), (S_kv,)
     Returns (B, S_q, Hq, D).
+
+    Positions left as None are the canonical ``0..S-1``. Then each query
+    chunk scans only the key chunks ``attention_schedule`` gives it (the
+    causal triangle, or a window's band); the chunks it skips are wholly
+    masked, and merging one adds exactly nothing. Explicit positions scan
+    every key chunk and leave the skipping to the mask.
     """
     from repro.models.module import BATCH, maybe_shard
     # keep heads sharded over "model" through the chunking reshapes — GSPMD
@@ -68,6 +107,15 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
     kv_chunk = min(kv_chunk, skv)
     nq = -(-sq // q_chunk)
     nk = -(-skv // kv_chunk)
+    if q_positions is None and kv_positions is None:
+        spans = attention_schedule(sq, skv, causal=causal, window=window,
+                                   q_chunk=q_chunk, kv_chunk=kv_chunk)
+    else:
+        spans = ((0, nk),) * nq
+    if q_positions is None:
+        q_positions = jnp.arange(sq)
+    if kv_positions is None:
+        kv_positions = jnp.arange(skv)
     # pad to multiples
     def pad_to(x, n, axis):
         pad = n - x.shape[axis]
@@ -90,39 +138,53 @@ def chunked_attention(q, k, v, *, q_positions, kv_positions, causal: bool,
     ks = kp.reshape(b, -1, nk, kv_chunk, d).transpose(2, 0, 1, 3, 4)
     vs = vp.reshape(b, -1, nk, kv_chunk, d).transpose(2, 0, 1, 3, 4)
 
-    def q_body(_, q_in):
-        qc, qpos_c = q_in  # (B,Hq,Tq,D), (Tq,)
+    def q_body(lo, hi):
+        """The scan over query chunks whose span is key chunks lo..hi-1."""
+        def body(_, q_in):
+            qc, qpos_c = q_in  # (B,Hq,Tq,D), (Tq,)
 
-        def kv_body(state, kv_in):
-            o_acc, m_acc, l_acc = state
-            kc, vc, kpos_c, kval_c = kv_in
-            mask = kval_c[None, :]
-            if causal:
-                mask = mask & (kpos_c[None, :] <= qpos_c[:, None])
-            if window is not None:
-                mask = mask & (kpos_c[None, :] > qpos_c[:, None] - window)
-            bias = jnp.where(mask, 0.0, NEG_INF)[None, None].astype(jnp.float32)
-            o, m, l = _attend_block(qc, kc, vc, bias)
-            m_new = jnp.maximum(m_acc, m)
-            c_old = jnp.exp(m_acc - m_new)
-            c_new = jnp.exp(m - m_new)
-            o_acc = o_acc * c_old.astype(o_acc.dtype) + o * c_new.astype(o.dtype)
-            l_acc = l_acc * c_old + l * c_new
-            return (o_acc, m_new, l_acc), None
+            def kv_body(state, j):
+                o_acc, m_acc, l_acc = state
+                kc, vc, kpos_c, kval_c = ks[j], vs[j], kpos[j], kvalid[j]
+                mask = kval_c[None, :]
+                if causal:
+                    mask = mask & (kpos_c[None, :] <= qpos_c[:, None])
+                if window is not None:
+                    mask = mask & (kpos_c[None, :] > qpos_c[:, None] - window)
+                bias = jnp.where(mask, 0.0, NEG_INF)[None, None].astype(
+                    jnp.float32)
+                o, m, l = _attend_block(qc, kc, vc, bias)
+                m_new = jnp.maximum(m_acc, m)
+                c_old = jnp.exp(m_acc - m_new)
+                c_new = jnp.exp(m - m_new)
+                o_acc = (o_acc * c_old.astype(o_acc.dtype)
+                         + o * c_new.astype(o.dtype))
+                l_acc = l_acc * c_old + l * c_new
+                return (o_acc, m_new, l_acc), None
 
-        o0 = jnp.zeros(qc.shape, jnp.float32)
-        m0 = jnp.full(qc.shape[:-1] + (1,), NEG_INF, jnp.float32)
-        l0 = jnp.zeros(qc.shape[:-1] + (1,), jnp.float32)
-        # checkpoint the kv step: otherwise backward stacks the (B,H,Tq,Tk)
-        # softmax residuals across ALL kv chunks (flash-attention memory
-        # blowup — the whole point of chunking would be lost)
-        (o, m, l), _ = jax.lax.scan(jax.checkpoint(kv_body), (o0, m0, l0),
-                                    (ks, vs, kpos, kvalid))
-        o = o / jnp.maximum(l, 1e-30)
-        return None, o.astype(q.dtype)
+            o0 = jnp.zeros(qc.shape, jnp.float32)
+            m0 = jnp.full(qc.shape[:-1] + (1,), NEG_INF, jnp.float32)
+            l0 = jnp.zeros(qc.shape[:-1] + (1,), jnp.float32)
+            # checkpoint the kv step: otherwise backward stacks the
+            # (B,H,Tq,Tk) softmax residuals across ALL kv chunks
+            # (flash-attention memory blowup — the whole point of chunking
+            # would be lost). The step indexes key chunk j of the whole
+            # stacks, so the backward adds each chunk's gradient into one
+            # buffer in place.
+            (o, m, l), _ = jax.lax.scan(jax.checkpoint(kv_body), (o0, m0, l0),
+                                        jnp.arange(lo, hi))
+            o = o / jnp.maximum(l, 1e-30)
+            return None, o.astype(q.dtype)
+        return body
 
-    _, outs = jax.lax.scan(q_body, None, (qs, qpos))
-    out = outs.transpose(1, 2, 0, 3, 4).reshape(b, hq, nq * q_chunk, d)
+    # one scan per run of query chunks that share a span (spans only grow)
+    outs, i = [], 0
+    for span, run in itertools.groupby(spans):
+        n = len(list(run))
+        _, o = jax.lax.scan(q_body(*span), None, (qs[i:i + n], qpos[i:i + n]))
+        outs.append(o)
+        i += n
+    out = jnp.concatenate(outs).transpose(1, 2, 0, 3, 4).reshape(b, hq, nq * q_chunk, d)
     return out[:, :, :sq].transpose(0, 2, 1, 3)
 
 
@@ -184,12 +246,13 @@ def _project_qkv(p, x, cfg: AttnConfig, positions):
 def gqa_apply(p, x, cfg: AttnConfig, *, positions=None, kv=None,
               kv_positions=None, q_chunk=512, kv_chunk=1024):
     """Full-sequence attention (train / prefill). Optional cross-attention via
-    precomputed ``kv=(k, v)`` (whisper decoder)."""
+    precomputed ``kv=(k, v)`` (whisper decoder). ``positions=None`` is
+    ``0..S-1``, which lets ``chunked_attention`` skip the key chunks no
+    query sees."""
     b, s, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(s)
     if kv is None:
-        q, k, v = _project_qkv(p, x, cfg, positions)
+        q, k, v = _project_qkv(
+            p, x, cfg, jnp.arange(s) if positions is None else positions)
         kv_positions = positions
         causal = cfg.causal
     else:
@@ -352,13 +415,13 @@ def mla_apply(p, x, cfg: MLAConfig, *, positions=None, q_chunk=512,
               kv_chunk=1024):
     """Prefill/train path: expand latent to per-head K/V, chunked attention.
     The chunked kernel scales scores by ``qk_head_dim**-0.5``; YaRN's
-    further ``mscale**2`` is folded into the queries."""
+    further ``mscale**2`` is folded into the queries. ``positions=None``
+    is ``0..S-1`` and takes the causal block schedule."""
     with jax.named_scope("mla"):
         b, s, _ = x.shape
-        if positions is None:
-            positions = jnp.arange(s)
-        q_nope, q_rope = _mla_q(p, x, cfg, positions)
-        c_kv, k_rope = _mla_latent(p, x, cfg, positions)
+        rope_pos = jnp.arange(s) if positions is None else positions
+        q_nope, q_rope = _mla_q(p, x, cfg, rope_pos)
+        c_kv, k_rope = _mla_latent(p, x, cfg, rope_pos)
         h = cfg.n_heads
         k_nope = dense_apply(p["wk_b"], c_kv).reshape(b, s, h,
                                                       cfg.qk_nope_dim)

@@ -44,6 +44,8 @@ def _scan_blocks(params_stack, x, apply_one, remat: bool):
 def forward(params, cfg: ModelConfig, tokens, *, embeds=None, positions=None):
     """tokens: (B, S_text) int32; embeds: modality-frontend output
     (encdec: (B, frames, d) encoder input; vlm: (B, n_patches, d) prepended).
+    ``positions=None`` is ``0..S_total-1``: attention then computes only
+    the key blocks its queries see (``attention.chunked_attention``).
     Returns (hidden (B, S_total, d), aux_loss)."""
     if cfg.family == "encdec":
         return _forward_encdec(params, cfg, tokens, embeds)
@@ -52,10 +54,6 @@ def forward(params, cfg: ModelConfig, tokens, *, embeds=None, positions=None):
     if cfg.family == "vlm":
         assert embeds is not None
         x = jnp.concatenate([embeds.astype(cfg.dtype), x], axis=1)
-    s = x.shape[1]
-    if positions is None:
-        positions = jnp.arange(s)
-
     aux_total = jnp.zeros((), jnp.float32)
     if cfg.family == "hybrid":
         x, aux_total = _forward_hybrid(params, cfg, x, positions)

@@ -198,22 +198,104 @@ def test_causality():
                                np.asarray(h2[0, :-1]), atol=1e-5)
 
 
-def test_chunked_attention_matches_naive():
+# (length, q_chunk, kv_chunk, causal, window, position offset): the
+# offset case passes explicit positions, which scan every key chunk
+ATTN_CASES = {
+    "causal": (37, 8, 16, True, None, None),
+    "window": (37, 8, 8, True, 10, None),
+    "ragged": (29, 8, 8, True, None, None),
+    "q_chunk_gt_kv_chunk": (64, 16, 8, True, None, None),
+    "explicit_positions": (40, 8, 16, True, 12, 1000),
+    "non_causal": (37, 8, 16, False, None, None),
+}
+
+
+def _naive_attention(q, k, v, qpos, kpos, causal, window):
+    s_ = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    mask = jnp.ones((len(qpos), len(kpos)), bool)
+    if causal:
+        mask = mask & (kpos[None, :] <= qpos[:, None])
+    if window is not None:
+        mask = mask & (kpos[None, :] > qpos[:, None] - window)
+    s_ = jnp.where(mask[None, None], s_, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, axis=-1), v)
+
+
+@pytest.mark.parametrize("case", sorted(ATTN_CASES))
+def test_chunked_attention_matches_naive(case):
+    """The block schedule (positions left canonical) gives the full
+    square's output and gradients, given the same positions explicitly,
+    and both match a naive softmax."""
     from repro.models.attention import chunked_attention
-    b, s, h, d = 2, 37, 4, 16
+    s, qc, kc, causal, window, offset = ATTN_CASES[case]
+    b, h, d = 2, 4, 16
     q = jax.random.normal(KEY, (b, s, h, d))
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, d))
     v = jax.random.normal(jax.random.PRNGKey(2), (b, s, h, d))
-    pos = jnp.arange(s)
-    got = chunked_attention(q, k, v, q_positions=pos, kv_positions=pos,
-                            causal=True, q_chunk=8, kv_chunk=16)
-    # naive reference
-    s_ = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    s_ = jnp.where(mask[None, None], s_, -1e30)
-    w = jax.nn.softmax(s_, axis=-1)
-    want = jnp.einsum("bhqk,bkhd->bqhd", w, v)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    cot = jax.random.normal(jax.random.PRNGKey(3), (b, s, h, d))
+    pos = jnp.arange(s) + (offset or 0)
+
+    def scheduled(q, k, v):
+        kw = {} if offset is None else {"q_positions": pos,
+                                        "kv_positions": pos}
+        return chunked_attention(q, k, v, causal=causal, window=window,
+                                 q_chunk=qc, kv_chunk=kc, **kw)
+
+    def full(q, k, v):
+        return chunked_attention(q, k, v, q_positions=jnp.arange(s),
+                                 kv_positions=jnp.arange(s), causal=causal,
+                                 window=window, q_chunk=qc, kv_chunk=kc)
+
+    def naive(q, k, v):
+        return _naive_attention(q, k, v, pos, pos, causal, window)
+
+    def out_and_grads(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(cot)
+
+    got, ref, want = map(out_and_grads, (scheduled, full, naive))
+    for name, g, r, w in zip(("out", "dq", "dk", "dv"), got, ref, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=1e-6,
+                                   rtol=1e-6, err_msg=name)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("case", sorted(ATTN_CASES))
+def test_attention_schedule_keeps_every_visible_block(case):
+    """A block is scheduled exactly when one of its (query, key) pairs is
+    visible at canonical positions."""
+    from repro.models.attention import attention_blocks, attention_schedule
+    s, qc, kc, causal, window, _ = ATTN_CASES[case]
+    qpos, kpos = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = np.ones((s, s), bool)
+    if causal:
+        seen &= kpos <= qpos
+    if window is not None:
+        seen &= kpos > qpos - window
+    nk = -(-s // kc)
+    live = [[seen[i * qc:(i + 1) * qc, j * kc:(j + 1) * kc].any()
+             for j in range(nk)] for i in range(-(-s // qc))]
+    spans = attention_schedule(s, s, causal=causal, window=window,
+                               q_chunk=qc, kv_chunk=kc)
+    assert [[lo <= j < hi for j in range(nk)] for lo, hi in spans] == live
+    assert attention_blocks(s, s, causal=causal, window=window, q_chunk=qc,
+                            kv_chunk=kc) == (int(np.sum(live)), nk * len(live))
+
+
+@pytest.mark.parametrize("causal,window,want", [
+    (True, None, (20, 32)),     # MLA at 4,096 tokens: 12 blocks above
+    (False, None, (32, 32)),    # the diagonal skipped, none without it
+    (True, 1024, (14, 32)),     # a window's band
+])
+def test_attention_blocks_at_4096_tokens(causal, window, want):
+    from repro.models.attention import attention_blocks, attention_schedule
+    assert attention_blocks(4096, 4096, causal=causal, window=window,
+                            q_chunk=512, kv_chunk=1024) == want
+    if window:
+        assert attention_schedule(4096, 4096, causal=causal, window=window,
+                                  q_chunk=512, kv_chunk=1024) == (
+            (0, 1), (0, 1), (0, 2), (0, 2), (1, 3), (1, 3), (2, 4), (2, 4))
 
 
 def test_ssd_chunked_matches_step_recurrence():

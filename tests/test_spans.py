@@ -16,6 +16,7 @@
   asks for one, and no wait is added where it does not.
 """
 import ast
+import dataclasses
 import glob
 import inspect
 import re
@@ -109,7 +110,40 @@ def test_one_round_span_per_round(program_events):
     assert [r[3]["step_num"] for r in rounds] == [0, 1]
     assert [(r[3]["participants"], r[3]["tiles"]) for r in rounds] == \
         [(COHORT, 1), (4, 2)]
+    assert not any({"attn_blocks", "attn_blocks_live"} & set(r[3])
+                   for r in rounds)           # a CNN has no attention
     assert {e[0] for e in program_events} <= set(runtime.SPANS)
+
+
+def test_lm_round_states_its_attention_blocks(tmp_path):
+    """A language model's ``fl.round`` carries the attention blocks a
+    layer has over one training sequence and those it computes."""
+    from repro.configs import get_config
+    seq = 32
+    cfg = dataclasses.replace(get_config("llama3.2-1b", reduced=True),
+                              n_layers=1, attn_q_chunk=8, attn_kv_chunk=16)
+    toks = np.random.default_rng(0).integers(0, cfg.vocab, (6, seq + 1))
+
+    def rows(t):
+        return {"tokens": t[:, :-1], "labels": t[:, 1:],
+                "mask": np.ones((len(t), seq), np.float32)}
+
+    task = runtime.lm_task(cfg, seq=seq)
+    assert task.attn_blocks == (6, 8)   # query chunks see 1, 1, 2, 2
+    fl = FLConfig(population=2, cohort_size=2, rounds=1, local_epochs=1,
+                  steps_per_epoch=1, batch_size=1, lr=0.01, momentum=0.9,
+                  method="fedavg", seed=0)
+    with jax.profiler.trace(str(tmp_path)):
+        run_federated(task, fl, [np.arange(2), np.arange(2, 4)],
+                      lambda sel: rows(toks[sel]), [rows(toks[4:])],
+                      log=lambda msg: None)
+    path, = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    rounds = [dict(ev.stats) for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name == "fl.round"]
+    assert [(r["attn_blocks"], r["attn_blocks_live"]) for r in rounds] == \
+        [(8, 6)]
 
 
 def test_one_pack_per_tile_with_its_loads(program_events):

@@ -1,5 +1,6 @@
 """The main path's Pallas kernels compile for a TPU v5e chip at the sizes
-Fed2 on VGG9 puts through them.
+Fed2 on VGG9 puts through them, and so does the causal attention of
+DeepSeek-V2-Lite's latent attention at 4,096 tokens.
 
 Nothing runs: each test compiles for a chip that is described, not
 attached, so the TPU compiler's refusals (block tiling, VMEM) surface here
@@ -21,6 +22,7 @@ from repro.core import fusion
 from repro.kernels import ops
 from repro.kernels.local_step import local_step_kernel
 from repro.kernels.paired_fusion import paired_fusion_kernel
+from repro.models.attention import chunked_attention
 from repro.models.cnn import init_cnn
 
 FED2_GROUPS = 8   # the launcher's --fed2-groups default
@@ -115,3 +117,21 @@ def test_local_step_compiles_for_v5e(one_chip, no_compile_cache, cohort):
     hlo = _compiled_text(step, spec, spec, spec)
     assert "tpu_custom_call" in hlo
     assert re.search(r"%local_step(\.\d+)? = ", hlo)
+
+
+def test_causal_attention_compiles_for_v5e_without_conditionals(
+        one_chip, no_compile_cache):
+    """MLA's shapes (16 heads, q/k width 192, V padded to it) at 4,096
+    tokens, forward and backward under the block's remat: the block
+    schedule is static, so the program holds no ``conditional``."""
+    spec = jax.ShapeDtypeStruct((1, 4096, 16, 192), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v):
+        attend = jax.checkpoint(
+            lambda q, k, v: chunked_attention(q, k, v, causal=True))
+        return jnp.sum(attend(q, k, v) ** 2)
+
+    hlo = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), spec, spec, spec)
+    assert " while(" in hlo
+    assert "conditional(" not in hlo
